@@ -37,7 +37,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.quantize import QuantizedActivation, QuantizedWeight
+from repro.core.quantize import QuantizedActivation, QuantizedWeight, lookup
 
 __all__ = [
     "build_lut",
@@ -91,8 +91,8 @@ def lut_gemm(
     constant hoisting); the reduction runs on the MXU. Bit-for-bit the same
     result as :func:`lut_gemm_counting` up to float summation order.
     """
-    a = (qa.codebook[qa.idx]).astype(compute_dtype)  # (..., K)
-    w = (qw.codebook[qw.indices]).astype(compute_dtype)  # (K, N)
+    a = lookup(qa.codebook, qa.idx).astype(compute_dtype)  # (..., K)
+    w = lookup(qw.codebook, qw.indices).astype(compute_dtype)  # (K, N)
     y = jnp.einsum("...k,kn->...n", a, w)
     return (y * qa.scale.astype(compute_dtype) * qw.scale.astype(compute_dtype)).astype(
         out_dtype

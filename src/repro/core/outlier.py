@@ -33,6 +33,8 @@ from repro.core.quantize import (
     QuantizedActivation,
     QuantizedWeight,
     dequantize_activation,
+    dequantize_weight,
+    lookup,
 )
 
 __all__ = [
@@ -152,7 +154,7 @@ def outlier_residuals_direct(
             idx += (v >= scale * b[i]).astype(jnp.int32)
     else:
         idx = cb.assign_via_boundaries((v / scale).astype(jnp.float32), codebook)
-    deq = codebook[idx] * scale
+    deq = lookup(codebook, idx) * scale
     return (v - deq) * out.mask
 
 
@@ -166,7 +168,7 @@ def compensate_gather(
     Unit), multiply-accumulate. Preferred when M (tokens) is small — decode.
     """
     w_idx_rows = jnp.take(qw.indices, out.channels, axis=0)  # (..., T, N)
-    w_rows = (qw.codebook[w_idx_rows] * qw.scale).astype(compute_dtype)
+    w_rows = (lookup(qw.codebook, w_idx_rows) * qw.scale).astype(compute_dtype)
     return jnp.einsum("...t,...tn->...n", residuals.astype(compute_dtype), w_rows)
 
 
@@ -197,7 +199,7 @@ def compensate_scatter(
     r_dense = jnp.zeros((*lead, k_channels), compute_dtype).at[
         (*idx, out.channels)
     ].add(residuals.astype(compute_dtype))
-    w = (qw.codebook[qw.indices] * qw.scale[None, :]).astype(compute_dtype)
+    w = dequantize_weight(qw, compute_dtype)
     return jnp.einsum("...k,kn->...n", r_dense, w)
 
 

@@ -296,7 +296,7 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
         xf = x.astype(cfg.compute_dtype)
         mask = (xf > p.thr_hi) | (xf < p.thr_lo)
         r = jnp.where(mask, xf - deq, 0)
-        w = (p.qw.codebook[p.qw.indices] * p.qw.scale[None, :]).astype(cfg.compute_dtype)
+        w = qz.dequantize_weight(p.qw, cfg.compute_dtype)
         y = y + jnp.einsum("...k,kn->...n", r, w)
     elif cfg.detection != "none" and cfg.outlier_frac > 0:
         if outs is None:

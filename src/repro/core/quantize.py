@@ -34,6 +34,7 @@ __all__ = [
     "QuantizedActivation",
     "pack_int4",
     "unpack_int4",
+    "lookup",
     "quantize_weight",
     "dequantize_weight",
     "token_scale",
@@ -66,6 +67,35 @@ def unpack_int4(packed: jax.Array) -> jax.Array:
     lo = (packed & 0xF).astype(jnp.int32)
     hi = (packed >> 4).astype(jnp.int32)
     return jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], packed.shape[-1] * 2)
+
+
+def lookup(codebook, idx: jax.Array) -> jax.Array:
+    """``codebook[idx]`` for a 1-D codebook of up to 256 entries, as compare-
+    select chains instead of a gather (same values).
+
+    Up to 16 entries this is one chain of selects; larger codebooks select
+    per high nibble among per-low-nibble chains. A TPU runs a gather from a
+    small table one element at a time: at h2o-danube-1.8B serving shapes
+    the codebook gathers took 5.3 s of a 5.7 s packed step on a v5e, the
+    chains a few ms. ``codebook`` may be a Pallas SMEM ref (read one scalar
+    at a time), so the kernels share this lookup.
+    """
+    n = codebook.shape[-1]
+    idx = idx.astype(jnp.int32)
+
+    def chain(sel, base, n_entries):
+        out = jnp.full(sel.shape, codebook[base], codebook.dtype)
+        for i in range(1, n_entries):
+            out = jnp.where(sel == i, codebook[base + i], out)
+        return out
+
+    if n <= 16:
+        return chain(idx, 0, n)
+    hi, lo = idx >> 4, idx & 0xF
+    out = chain(lo, 0, 16)
+    for h in range(1, -(-n // 16)):
+        out = jnp.where(hi == h, chain(lo, 16 * h, min(16, n - 16 * h)), out)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +200,7 @@ def quantize_weight(w: jax.Array, nbits: int = 4, iters: int = 25,
 
 def dequantize_weight(qw: QuantizedWeight, dtype=jnp.float32) -> jax.Array:
     """W~[k, n] = C[idx[k, n]] * scale[n]."""
-    return (qw.codebook[qw.indices] * qw.scale[None, :]).astype(dtype)
+    return (lookup(qw.codebook, qw.indices) * qw.scale[None, :]).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +248,7 @@ def quantize_activation(
 
 
 def dequantize_activation(qa: QuantizedActivation, dtype=jnp.float32) -> jax.Array:
-    return (qa.codebook[qa.idx] * qa.scale).astype(dtype)
+    return (lookup(qa.codebook, qa.idx) * qa.scale).astype(dtype)
 
 
 def fit_activation_codebook(

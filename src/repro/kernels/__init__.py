@@ -7,7 +7,8 @@
                  int4 dequant-in-VMEM)
 
 ``ops`` holds the jit'd public wrappers, ``ref`` the pure-jnp oracles.
-Kernels are validated in interpret mode on CPU and lower unchanged on TPU.
+Kernels are validated in interpret mode on CPU; tests/test_tpu_compile.py
+compiles them for a described TPU v5e at published widths.
 """
 
 from repro.kernels import ops, ref

@@ -1,14 +1,14 @@
 """Pallas TPU kernels: K-Means index GEMMs (the paper's LUT-GEMM on MXU).
 
-TPU-native formulation of the Cartesian-product LUT GEMM (DESIGN.md §2),
-in three variants sharing one tiling scheme:
+TPU-native formulation of the Cartesian-product LUT GEMM, in two variants
+sharing one tiling scheme:
 
 * :func:`lut_gemm_kernel_call` — index-in, W4A4-style **nibble tier**
   (``nbits <= 4``: two 4-bit weight indices per byte) and the byte-packed
-  **W5–W8 tier** (``byte_packed=True``: one index per byte). Per 128-aligned
-  VMEM tile we unpack indices with integer bit ops, look centroids up
-  on-chip, and feed the MXU with the dequantized tile, accumulating f32
-  partials across the K grid dimension.
+  **W5–W8 tier** (``byte_packed=True``: one index per byte). Per VMEM tile we
+  unpack indices with integer bit ops, look centroids up on-chip, and feed
+  the MXU with the dequantized tile, accumulating f32 partials across the K
+  grid dimension.
 
 * :func:`fused_lut_gemm_kernel_call` — **fused quantize+GEMM**: takes raw
   activations plus their per-token scale, bucketizes against the activation
@@ -17,18 +17,20 @@ in three variants sharing one tiling scheme:
   immediately runs the index-GEMM. Activation indices exist only in VMEM —
   the separate quantize pass and its idx HBM roundtrip are gone.
 
-Centroid lookup is tiered by codebook size:
+Nibble planes. A packed byte holds output channels ``2i`` (low nibble) and
+``2i+1`` (high nibble) — the format ``core.quantize.pack_int4`` owns. Rather
+than interleaving the two nibble planes back along the lane axis (a minor-
+dim relayout Mosaic does not lower), each plane is its own MXU dot into its
+own output: the kernel emits the even and the odd output channels as two
+lane-dense (M, N/2) arrays, and the wrapper interleaves them in XLA. A
+``block_n`` tile therefore reads a (bk, block_n/2) packed block, so the
+default ``block_n=256`` gives the 128-lane blocks the TPU tiling asks for.
 
-  2^n <= 16 : compare-select chain — 15 vselects IS the LUT lookup, the
-              codebook lives in registers (TPU analogue of the ASIC's
-              on-chip LUT).
-  2^n  > 16 : the chain is untenable at 256 entries (255 serial selects per
-              element), so the byte tier splits each index into two nibbles
-              and looks up ``book[16*hi + lo]`` via a one-hot matmul against
-              the codebook laid out as a (16, 16) VMEM table:
-              ``t[e, h] = book2d[h, lo[e]]`` (one (E,16)x(16,16) MXU dot),
-              then a 16-wide masked row-sum selects ``t[e, hi[e]]`` — 2x16
-              compares + one tiny matmul instead of 255 selects.
+Centroid lookup is ``core.quantize.lookup`` against codebook scalars held
+in SMEM: a chain of 15 selects for a 16-entry codebook. The byte tier
+selects ``book[16*hi + lo]`` as a chain over ``hi`` of chains over ``lo``;
+every intermediate is a lane-dense (bk, bn) tile, so the VMEM working set
+stays a few tiles wide.
 
 No dequantized weight matrix ever exists in HBM — HBM traffic is the packed
 index bytes plus <= 1 KiB of codebook, i.e. the paper's "no-dequantization"
@@ -48,54 +50,47 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.quantize import lookup
 
 __all__ = ["lut_gemm_kernel_call", "fused_lut_gemm_kernel_call"]
 
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole array, scalar reads
 
-def _deq_select(idx: jax.Array, book: jax.Array, n_entries: int) -> jax.Array:
-    """Centroid lookup as a compare-select chain (VPU-friendly 16-way LUT).
 
-    out[...] = book[idx[...]] without a hardware gather: for codebooks of
-    <= 2^4 entries this is <= 15 vselects — cheap relative to the MXU dot it
-    feeds, and it vectorizes perfectly on 8x128 vregs.
+def mxu_precision():
+    """Precision of the kernels' MXU dots, read at trace time.
+
+    Kernel dots follow ``jax.default_matmul_precision`` as XLA's dots do:
+    "highest"/"float32" asks Mosaic for full f32 passes; anything else keeps
+    Mosaic's default (bf16 passes for f32 operands).
     """
-    out = jnp.full(idx.shape, book[0], jnp.float32)
-    for i in range(1, n_entries):
-        out = jnp.where(idx == i, book[i], out)
-    return out
+    p = jax.config.jax_default_matmul_precision
+    return jax.lax.Precision.HIGHEST if p in ("highest", "float32") else None
 
 
-def _lookup(idx: jax.Array, book2d: jax.Array, nbits: int) -> jax.Array:
-    """book[idx] for a codebook stored as a padded (16, 16) VMEM table.
-
-    nbits <= 4 uses the compare-select chain on the table's flat head;
-    nbits in (5..8] uses the nibble-decomposed one-hot matmul (module
-    docstring): ``book[idx] = sum_h 1[hi=h] * (onehot(lo) @ book2d.T)[h]``.
-    """
-    if nbits <= 4:
-        return _deq_select(idx, book2d.reshape(-1), 2**nbits)
-    hi = idx >> 4
-    lo = idx & 0xF
-    lane = jax.lax.broadcasted_iota(jnp.int32, (*idx.shape, 16), idx.ndim)
-    oh_lo = (lo[..., None] == lane).astype(jnp.float32)  # (..., 16)
-    t = jnp.dot(
-        oh_lo.reshape(-1, 16), book2d.T, preferred_element_type=jnp.float32
-    ).reshape(*idx.shape, 16)  # t[e, h] = book2d[h, lo[e]] = book[16h + lo[e]]
-    oh_hi = (hi[..., None] == lane).astype(jnp.float32)
-    return jnp.sum(oh_hi * t, axis=-1)
+def _weight_planes(w_vals: jax.Array, w_book,
+                   byte_packed: bool) -> tuple[jax.Array, ...]:
+    """Dequantize one packed weight tile to its f32 output-channel planes:
+    (bk, bn) for the byte tier, (even, odd) (bk, bn/2) for the nibble tier."""
+    w = w_vals.astype(jnp.int32)
+    if byte_packed:  # one index per byte
+        return (lookup(w_book, w),)
+    return lookup(w_book, w & 0xF), lookup(w_book, w >> 4)
 
 
-def _deq_weight_tile(w_vals: jax.Array, book2d: jax.Array, n_w: int,
-                     byte_packed: bool) -> jax.Array:
-    """Dequantize one (bk, ...) weight-index tile to (bk, bn) f32."""
-    if byte_packed:  # (bk, bn) uint8, one index per byte
-        return _lookup(w_vals.astype(jnp.int32), book2d, n_w)
-    lo = _lookup((w_vals & 0xF).astype(jnp.int32), book2d, n_w)
-    hi = _lookup((w_vals >> 4).astype(jnp.int32), book2d, n_w)
-    # Interleave even/odd output channels on the minor axis: (bk, bn//2, 2) ->
-    # (bk, bn). A minor-dim relayout on TPU; deinterleaved packing is the
-    # documented alternative if this ever dominates (see EXPERIMENTS §Perf).
-    return jnp.stack([lo, hi], axis=-1).reshape(w_vals.shape[0], -1)
+def _accumulate(a, w_ref, w_book_ref, o_refs, *, byte_packed: bool):
+    """o_refs[p] += a @ plane_p over the K grid axis (zeroed at kk == 0)."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        for o_ref in o_refs:
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+    planes = _weight_planes(w_ref[...], w_book_ref, byte_packed)
+    for o_ref, w in zip(o_refs, planes):
+        o_ref[...] += jnp.dot(a, w, precision=mxu_precision(),
+                              preferred_element_type=jnp.float32)
 
 
 def _mask_padded_k(a: jax.Array, block_k: int, k_true: int) -> jax.Array:
@@ -111,29 +106,18 @@ def _mask_padded_k(a: jax.Array, block_k: int, k_true: int) -> jax.Array:
     return jnp.where(col < k_true, a, 0.0)
 
 
-def _pad_book_2d(book: jax.Array) -> jax.Array:
-    """Codebook -> zero-padded 256-entry (16, 16) table (row = high nibble)."""
-    book = book.astype(jnp.float32).reshape(-1)
-    return jnp.pad(book, (0, 256 - book.shape[0])).reshape(16, 16)
-
-
-def _index_kernel(a_idx_ref, w_ref, a_book_ref, w_book_ref, o_ref, *,
-                  n_a: int, n_w: int, byte_packed: bool, block_k: int,
+def _index_kernel(a_idx_ref, w_ref, a_book_ref, w_book_ref, *o_refs,
+                  byte_packed: bool, block_k: int,
                   k_true: int, masked_k: bool):
     """Grid: (M/bm, N/bn, K/bk); K is the innermost (arbitrary) dimension."""
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    a = _lookup(a_idx_ref[...], a_book_ref[...], n_a)  # (bm, bk) f32
+    a = lookup(a_book_ref, a_idx_ref[...])  # (bm, bk) f32
     if masked_k:
         a = _mask_padded_k(a, block_k, k_true)
-    w = _deq_weight_tile(w_ref[...], w_book_ref[...], n_w, byte_packed)
-    o_ref[...] += jnp.dot(a, w, preferred_element_type=jnp.float32)
+    _accumulate(a, w_ref, w_book_ref, o_refs, byte_packed=byte_packed)
 
 
 def _fused_kernel(x_ref, s_ref, w_ref, bounds_ref, a_book_ref, w_book_ref,
-                  o_ref, *, n_a: int, n_w: int, byte_packed: bool,
+                  *o_refs, byte_packed: bool,
                   mul_form: bool, block_k: int, k_true: int, masked_k: bool):
     """Bucketize-then-GEMM in one pass: activation indices never leave VMEM.
 
@@ -142,27 +126,21 @@ def _fused_kernel(x_ref, s_ref, w_ref, bounds_ref, a_book_ref, w_book_ref,
     f32 compares ``x/s >= b_i`` (the searchsorted path), bf16 compares
     ``x >= s*b_i`` (the fused sum-of-compares path).
     """
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
     x = x_ref[...].astype(jnp.float32)  # (bm, bk)
     s = s_ref[...].astype(jnp.float32)  # (bm, 1) per-token scale
-    b = bounds_ref[...]  # (2^n_a - 1,) decision boundaries
     idx = jnp.zeros(x.shape, jnp.int32)
     if mul_form:
-        for i in range(2**n_a - 1):
-            idx += (x >= s * b[i]).astype(jnp.int32)
+        for i in range(bounds_ref.shape[0]):
+            idx += (x >= s * bounds_ref[i]).astype(jnp.int32)
     else:
         xn = x / s
-        for i in range(2**n_a - 1):
-            idx += (xn >= b[i]).astype(jnp.int32)
+        for i in range(bounds_ref.shape[0]):
+            idx += (xn >= bounds_ref[i]).astype(jnp.int32)
 
-    a = _lookup(idx, a_book_ref[...], n_a)
+    a = lookup(a_book_ref, idx)
     if masked_k:
         a = _mask_padded_k(a, block_k, k_true)
-    w = _deq_weight_tile(w_ref[...], w_book_ref[...], n_w, byte_packed)
-    o_ref[...] += jnp.dot(a, w, preferred_element_type=jnp.float32)
+    _accumulate(a, w_ref, w_book_ref, o_refs, byte_packed=byte_packed)
 
 
 def _grid_geometry(m: int, n: int, k: int, block_m: int | None,
@@ -170,23 +148,57 @@ def _grid_geometry(m: int, n: int, k: int, block_m: int | None,
                    byte_packed: bool):
     """Clamp block sizes and compute padded grid extents.
 
-    Byte tiers default to a smaller K block: the one-hot lookup holds two
-    (bk, bn, 16) f32 intermediates per tile (bk=256, bn=128 -> 4 MiB), and
-    the default keeps the working set well inside the ~16 MiB/core VMEM.
+    ``block_n`` counts logical output channels for both tiers, so a nibble
+    tile reads a (bk, bn/2) packed block. Byte tiers default to a smaller K
+    block: the two-level select chain keeps a few more (bk, bn) tiles live.
 
     VMEM working set per step (nibble defaults, W4A4):
-      a_idx 128x512 int32 = 256 KiB, w 512x64 uint8 = 32 KiB,
-      deq tiles (128x512 + 512x128) f32 = 512 KiB, acc 128x128 f32 = 64 KiB
-    -> < 1 MiB, comfortable with double-buffering.
+      a_idx 128x512 int32 = 256 KiB, w 512x128 uint8 = 64 KiB (int32 copy
+      256 KiB), two planes 512x128 f32 = 512 KiB, acc 2x 128x128 f32 = 128 KiB
+    -> ~1.2 MiB, comfortable with double-buffering.
     """
     bm = min(block_m or 128, m)
-    bn = min(block_n or 128, n)
+    bn = min(block_n or (128 if byte_packed else 256), n)
     bk = min(block_k or (256 if byte_packed else 512), k)
     if not byte_packed and bn % 2:
         raise ValueError("block_n must be even (nibble packing)")
     pm, pn, pk = (-m) % bm, (-n) % bn, (-k) % bk
     grid = ((m + pm) // bm, (n + pn) // bn, (k + pk) // bk)
     return bm, bn, bk, pm, pn, pk, grid
+
+
+def _index_gemm_call(kernel, geometry, row_args, w_packed, books, *, m: int,
+                     n: int, k: int, byte_packed: bool, interpret: bool,
+                     **kernel_kw):
+    """Shared pallas_call plumbing for both index-GEMM variants.
+
+    ``row_args`` are the activation-side inputs, already padded to the grid:
+    (M, K) tiles or (M, 1) per-token columns. ``books`` are the scalar
+    tables that go to SMEM after the weights (boundaries, codebooks).
+    """
+    bm, bn, bk, pm, pn, pk, grid = geometry
+    w_packed = jnp.pad(w_packed, ((0, pk), (0, pn if byte_packed else pn // 2)))
+    wn = bn if byte_packed else bn // 2  # packed block width == plane width
+    n_planes = 1 if byte_packed else 2
+    row_specs = [pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0))
+                 if a.shape[1] == 1 else
+                 pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
+                 for a in row_args]
+    outs = pl.pallas_call(
+        functools.partial(kernel, byte_packed=byte_packed, block_k=bk,
+                          k_true=k, masked_k=pk > 0, **kernel_kw),
+        grid=grid,
+        in_specs=row_specs
+        + [pl.BlockSpec((bk, wn), lambda i, j, kk: (kk, j))]
+        + [_SMEM] * len(books),
+        out_specs=[pl.BlockSpec((bm, wn), lambda i, j, kk: (i, j))] * n_planes,
+        out_shape=[jax.ShapeDtypeStruct((m + pm, (n + pn) // n_planes),
+                                        jnp.float32)] * n_planes,
+        interpret=interpret,
+    )(*row_args, w_packed, *books)
+    # nibble planes hold the even / odd output channels: interleave in XLA
+    y = outs[0] if byte_packed else jnp.stack(outs, axis=-1).reshape(m + pm, -1)
+    return y[:m, :n]
 
 
 def lut_gemm_kernel_call(
@@ -208,34 +220,13 @@ def lut_gemm_kernel_call(
     """
     m, k = a_idx.shape
     n = w_packed.shape[1] * (1 if byte_packed else 2)
-    bm, bn, bk, pm, pn, pk, grid = _grid_geometry(
-        m, n, k, block_m, block_n, block_k, byte_packed)
-    if pm or pk:
-        a_idx = jnp.pad(a_idx, ((0, pm), (0, pk)))
-    if pn or pk:
-        wn_pad = pn if byte_packed else pn // 2
-        w_packed = jnp.pad(w_packed, ((0, pk), (0, wn_pad)))
-    wn_block = bn if byte_packed else bn // 2
-
-    out = pl.pallas_call(
-        functools.partial(
-            _index_kernel,
-            n_a=int(a_book.shape[0]).bit_length() - 1,
-            n_w=int(w_book.shape[0]).bit_length() - 1,
-            byte_packed=byte_packed, block_k=bk, k_true=k, masked_k=pk > 0,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, wn_block), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((16, 16), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((16, 16), lambda i, j, kk: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), jnp.float32),
-        interpret=interpret,
-    )(a_idx, w_packed, _pad_book_2d(a_book), _pad_book_2d(w_book))
-    return out[:m, :n]
+    geo = _grid_geometry(m, n, k, block_m, block_n, block_k, byte_packed)
+    pm, pk = geo[3], geo[5]
+    return _index_gemm_call(
+        _index_kernel, geo, [jnp.pad(a_idx, ((0, pm), (0, pk)))], w_packed,
+        [a_book.astype(jnp.float32), w_book.astype(jnp.float32)],
+        m=m, n=n, k=k, byte_packed=byte_packed, interpret=interpret,
+    )
 
 
 def fused_lut_gemm_kernel_call(
@@ -258,42 +249,19 @@ def fused_lut_gemm_kernel_call(
     The per-token scale needs a full-K reduction so it is computed by the
     caller (a rank-1 pass XLA fuses); everything O(M*K) — bucketize, index,
     centroid lookup — happens inside the tile. Padded rows must carry a
-    nonzero ``scale`` (the ops.py wrapper pads with ones) so the in-kernel
+    nonzero ``scale`` (this wrapper pads with ones) so the in-kernel
     division stays NaN-free; padded rows are sliced off regardless.
     """
     m, k = x.shape
     n = w_packed.shape[1] * (1 if byte_packed else 2)
-    bm, bn, bk, pm, pn, pk, grid = _grid_geometry(
-        m, n, k, block_m, block_n, block_k, byte_packed)
-    if pm or pk:
-        x = jnp.pad(x, ((0, pm), (0, pk)))
-    if pm:
-        scale = jnp.pad(scale, ((0, pm), (0, 0)), constant_values=1.0)
-    if pn or pk:
-        wn_pad = pn if byte_packed else pn // 2
-        w_packed = jnp.pad(w_packed, ((0, pk), (0, wn_pad)))
-    wn_block = bn if byte_packed else bn // 2
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_kernel,
-            n_a=int(a_book.shape[0]).bit_length() - 1,
-            n_w=int(w_book.shape[0]).bit_length() - 1,
-            byte_packed=byte_packed, mul_form=mul_form,
-            block_k=bk, k_true=k, masked_k=pk > 0,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((bk, wn_block), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec(bounds.shape, lambda i, j, kk: (0,)),
-            pl.BlockSpec((16, 16), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((16, 16), lambda i, j, kk: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), jnp.float32),
-        interpret=interpret,
-    )(x, scale.astype(jnp.float32), w_packed, bounds.astype(jnp.float32),
-      _pad_book_2d(a_book), _pad_book_2d(w_book))
-    return out[:m, :n]
+    geo = _grid_geometry(m, n, k, block_m, block_n, block_k, byte_packed)
+    pm, pk = geo[3], geo[5]
+    s = jnp.pad(scale.astype(jnp.float32), ((0, pm), (0, 0)),
+                constant_values=1.0)
+    return _index_gemm_call(
+        _fused_kernel, geo, [jnp.pad(x, ((0, pm), (0, pk))), s], w_packed,
+        [bounds.astype(jnp.float32), a_book.astype(jnp.float32),
+         w_book.astype(jnp.float32)],
+        m=m, n=n, k=k, byte_packed=byte_packed, interpret=interpret,
+        mul_form=mul_form,
+    )

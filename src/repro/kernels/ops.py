@@ -2,8 +2,12 @@
 
 These adapt the kernels to the core library's types (QuantizedActivation /
 QuantizedWeight / OutlierSet), handle arbitrary leading batch dims, apply the
-rank-1 scales, and auto-select interpret mode off-TPU (the container is
-CPU-only; on a real TPU ``interpret=False`` compiles the same kernels).
+rank-1 scales, and auto-select interpret mode off-TPU. On a TPU they run
+compiled (``interpret=False``): ``tests/test_tpu_compile.py`` compiles the
+fused LUT-GEMM (W4 nibble and W8 byte tiers, f32 and bf16 inputs), both
+Orizuru kernels and both paged-attention pools for a described v5e at
+h2o-danube-1.8B widths, and ``chip_smoke.py`` serves that model through
+all of them on a v5e chip.
 
 ``lut_gemm`` dispatches both weight tiers (nibble-packed <= 4 bits, byte-
 packed 5..8 bits); ``lut_gemm_fused`` is the serving hot path: raw
@@ -55,13 +59,15 @@ def _flatten_leading(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
 # traced before the sweep keeps its compiled defaults (jit caches by shape).
 _BLOCK_CACHE: dict[tuple, tuple[int, int, int]] = {}
 
+# block_n >= 256 keeps a nibble tile's packed block (block_n / 2 lanes) on
+# the TPU's 128-lane tiling
 _CANDIDATES = (
-    (128, 128, 512),
-    (128, 128, 256),
+    (128, 256, 512),
     (128, 256, 256),
-    (256, 128, 128),
-    (64, 128, 256),
-    (8, 128, 512),
+    (128, 512, 256),
+    (256, 256, 128),
+    (64, 256, 256),
+    (8, 256, 512),
 )
 
 

@@ -5,25 +5,28 @@ SHARED LEAF COMPARISONS: the N/2 pairwise compares that initialize the max
 tree's first level are reused (reversed) for the min tree, giving
 1.5N + 2k·log2(N) comparisons instead of ~3N (or 6N for SpAtten's engine).
 
-TPU adaptation (DESIGN.md §2): the serial pop-one-per-cycle loop is an ASIC
-latency trick with no TPU analogue — a vectorized argmax over a vreg-resident
-array has O(log N) depth anyway. What we keep is the *shared-pairwise* trick
-and the *pair-collapse* structure:
+TPU adaptation: the serial pop-one-per-cycle loop is an ASIC latency trick
+with no TPU analogue — a vectorized max over a vreg-resident array has
+O(log N) depth anyway. What we keep is the *shared-pairwise* trick and the
+*pair-collapse* structure:
 
-  phase 1 (shared): A = max(x_even, x_odd), B = min(x_even, x_odd)
+  phase 1 (shared): A = max(x_lo_half, x_hi_half), B = min(...)
                     — N/2 compares produce level-1 of BOTH trees;
-  phase 2 (pop):    k iterations of argmax over the N/2-wide A-array; a popped
+  phase 2 (pop):    k iterations of max over the N/2-wide A-array; a popped
                     pair falls back to its other leaf (B) and then to -inf —
                     exactly the paper's tree-maintenance semantics, k·(N/2)
                     vector-lanes of work but only k sequential steps;
   min side:         the SAME pop routine on (-B, -A) — comparisons reused.
 
-Odd N is handled by padding one lane that is −inf on the max side and +inf
-on the min side, so the pad can never be selected while k <= N real values
-remain (indices therefore never point at the pad). On the even path the two
-sides still share one set of pairwise comparisons bit-for-bit.
+Pairs are lane ``j`` with lane ``j + N/2``: both halves are lane-aligned
+slices, where pairing neighbours would need a stride-2 lane shuffle. N is
+padded to a multiple of 256 with lanes that are −inf on the max side and
++inf on the min side, so a pad can never be selected while k <= N real
+values remain (indices therefore never point at a pad). All reductions run
+in f32 (lane indices are exact there), the one type Mosaic reduces.
 
-Tie-breaking matches the paper: the left child wins in both trees, which
+Tie-breaking: the left child (lower index) wins within a pair, and among
+equal pair fronts the lowest original index is popped first, which
 reproduces lax.top_k's ascending-index order on equal values (asserted in
 tests against the sort-based oracle, including duplicate-heavy and
 all-equal inputs).
@@ -41,11 +44,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["topk_outlier_kernel_call", "streaming_quantize_outlier_kernel_call"]
 
 _NEG_INF = float("-inf")  # plain literal: jnp constants would be captured consts in the kernel
 _POS_INF = float("inf")
+_BIG = float(2**30)  # above every lane index, exact in f32
+_LANE_PAIRS = 256  # N pads to this so both pair halves are 128-lane aligned
 
 
 def _default_interpret(interpret: bool | None) -> bool:
@@ -58,37 +64,34 @@ def _pop_topk(cur, fallback, idx_cur, idx_fb, k: int):
 
     cur      : (bm, P) current per-pair front value (pair maxima)
     fallback : (bm, P) the other leaf of each pair
-    idx_cur/idx_fb : original column indices of cur/fallback entries
-    Returns (vals (bm, k) descending, idx (bm, k)).
+    idx_cur/idx_fb : original column indices of cur/fallback entries (f32)
+    Returns (vals (bm, k) descending, idx (bm, k) f32).
     """
     bm, p = cur.shape
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, p), 1)
     col_k = jax.lax.broadcasted_iota(jnp.int32, (bm, k), 1)
-    cnt = jnp.zeros((bm, p), jnp.int32)
+    state = jnp.zeros((bm, p), jnp.float32)  # pops taken from each pair
     vals = jnp.full((bm, k), _NEG_INF)
-    idxs = jnp.zeros((bm, k), jnp.int32)
+    idxs = jnp.zeros((bm, k), jnp.float32)
 
     def body(t, carry):
-        cur, cnt, vals, idxs = carry
-        v = jnp.max(cur, axis=1)  # (bm,)
-        # first-True argmax == lowest pair index on ties (left-child rule)
-        is_max = cur == v[:, None]
-        j = jnp.argmax(is_max, axis=1).astype(jnp.int32)  # (bm,)
-        onehot = lane == j[:, None]
-        cnt_j = jnp.sum(jnp.where(onehot, cnt, 0), axis=1)  # (bm,)
-        first_pop = cnt_j == 0
-        take = lambda a: jnp.sum(jnp.where(onehot, a, 0), axis=1)
-        takef = lambda a: jnp.sum(jnp.where(onehot, a, 0.0), axis=1)
-        orig = jnp.where(first_pop, take(idx_cur), take(idx_fb))
-        repl = jnp.where(first_pop, takef(fallback), _NEG_INF)
-        cur = jnp.where(onehot, repl[:, None], cur)
-        cnt = cnt + onehot.astype(jnp.int32)
+        cur, idx_cur, state, vals, idxs = carry
+        v = jnp.max(cur, axis=1, keepdims=True)  # (bm, 1)
+        # lowest original index among the maxima (lax.top_k's tie order);
+        # live fronts hold distinct leaves, so exactly one pair is hit
+        orig = jnp.min(jnp.where(cur == v, idx_cur, _BIG), axis=1,
+                       keepdims=True)
+        hit = idx_cur == orig
+        fresh = state == 0.0
+        cur = jnp.where(hit, jnp.where(fresh, fallback, _NEG_INF), cur)
+        idx_cur = jnp.where(hit & fresh, idx_fb, idx_cur)
+        state = state + hit.astype(jnp.float32)
         write = col_k == t
-        vals = jnp.where(write, v[:, None], vals)
-        idxs = jnp.where(write, orig[:, None], idxs)
-        return cur, cnt, vals, idxs
+        vals = jnp.where(write, v, vals)
+        idxs = jnp.where(write, orig, idxs)
+        return cur, idx_cur, state, vals, idxs
 
-    _, _, vals, idxs = jax.lax.fori_loop(0, k, body, (cur, cnt, vals, idxs))
+    carry = (cur, idx_cur, state, vals, idxs)
+    _, _, _, vals, idxs = jax.lax.fori_loop(0, k, body, carry)
     return vals, idxs
 
 
@@ -100,9 +103,10 @@ def _dual_topk(x, k: int, n_valid: int):
     real data a pad lane is never popped (its fallback is the sign-flipped
     pad, i.e. worse than any real value on either side). With n_valid == n
     both trees read the SAME array and the pairwise comparisons are shared.
-    Returns (hi_v desc, hi_i, lo_v asc, lo_i).
+    Returns (hi_v desc, hi_i, lo_v asc, lo_i); indices as int32.
     """
     bm, n = x.shape
+    h = n // 2
     if n_valid < n:
         col = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 1)
         x_hi = jnp.where(col < n_valid, x, _NEG_INF)
@@ -110,31 +114,26 @@ def _dual_topk(x, k: int, n_valid: int):
     else:
         x_hi = x_lo = x
 
-    pair = jax.lax.broadcasted_iota(jnp.int32, (bm, n // 2), 1) * 2
+    left = jax.lax.broadcasted_iota(jnp.int32, (bm, h), 1).astype(jnp.float32)
+    right = left + float(h)
 
-    # --- shared pairwise comparisons (level-1 of both trees): N/2 compares ---
-    xp = x_hi.reshape(bm, n // 2, 2)
-    xe, xo = xp[..., 0], xp[..., 1]
-    right_wins_max = xo > xe  # strict: ties go left (paper's rule)
-    a = jnp.where(right_wins_max, xo, xe)  # pair maxima
-    b = jnp.where(right_wins_max, xe, xo)  # pair minima (max-tree fallback)
-    # Each tree keeps its own leaf mask (paper: m^(p) vs m^(q)), so primary and
-    # fallback indices are complements PER TREE — on a tie both trees pick the
-    # left child first and fall back to the right one.
-    a_idx = jnp.where(right_wins_max, pair + 1, pair)
-    a_fb_idx = jnp.where(right_wins_max, pair, pair + 1)
+    def level1(xs, right_wins):
+        """Pair fronts, fallbacks and their leaf indices for one tree."""
+        xl, xr = xs[:, :h], xs[:, h:]
+        rw = right_wins(xl, xr)  # strict: ties go left (paper's rule)
+        return (jnp.where(rw, xr, xl), jnp.where(rw, xl, xr),
+                jnp.where(rw, right, left), jnp.where(rw, left, right))
 
-    xp = x_lo.reshape(bm, n // 2, 2)
-    xe, xo = xp[..., 0], xp[..., 1]
-    right_wins_min = xo < xe
-    c = jnp.where(right_wins_min, xo, xe)  # pair minima
-    d = jnp.where(right_wins_min, xe, xo)  # pair maxima (min-tree fallback)
-    c_idx = jnp.where(right_wins_min, pair + 1, pair)
-    c_fb_idx = jnp.where(right_wins_min, pair, pair + 1)
+    # --- shared pairwise comparisons (level-1 of both trees): N/2 compares.
+    # Each tree keeps its own leaf mask (paper: m^(p) vs m^(q)), so primary
+    # and fallback indices are complements PER TREE — on a tie both trees
+    # pick the left child first and fall back to the right one.
+    a, b, a_idx, a_fb_idx = level1(x_hi, lambda xl, xr: xr > xl)
+    c, d, c_idx, c_fb_idx = level1(x_lo, lambda xl, xr: xr < xl)
 
     hi_v, hi_i = _pop_topk(a, b, a_idx, a_fb_idx, k)
     neg_v, lo_i = _pop_topk(-c, -d, c_idx, c_fb_idx, k)
-    return hi_v, hi_i, -neg_v, lo_i
+    return hi_v, hi_i.astype(jnp.int32), -neg_v, lo_i.astype(jnp.int32)
 
 
 def _kernel(x_ref, hi_v_ref, hi_i_ref, lo_v_ref, lo_i_ref, *, k: int,
@@ -158,15 +157,14 @@ def _streaming_kernel(x_ref, s_ref, b_ref, idx_ref, hi_v_ref, hi_i_ref,
     """
     x = x_ref[...]  # (bm, n) f32
     s = s_ref[...]  # (bm, 1) f32
-    b = b_ref[...]
     idx = jnp.zeros(x.shape, jnp.int32)
     if mul_form:
         for i in range(n_boundaries):
-            idx += (x >= s * b[i]).astype(jnp.int32)
+            idx += (x >= s * b_ref[i]).astype(jnp.int32)
     else:
         xd = x / s
         for i in range(n_boundaries):
-            idx += (xd >= b[i]).astype(jnp.int32)
+            idx += (xd >= b_ref[i]).astype(jnp.int32)
     idx_ref[...] = idx
     hi_v, hi_i, lo_v, lo_i = _dual_topk(x, k, n_valid)
     hi_v_ref[...] = hi_v
@@ -176,7 +174,8 @@ def _streaming_kernel(x_ref, s_ref, b_ref, idx_ref, hi_v_ref, hi_i_ref,
 
 
 def _pad_args(x: jax.Array, k: int, block_m: int):
-    """Shared shape plumbing: pad odd N by one lane and M to a block multiple.
+    """Shared shape plumbing: pad N to whole lane pairs and M to a block
+    multiple.
 
     Returns (x padded f32, bm, grid_m, mp (padded rows), n_valid, np (padded
     cols)). Pad lanes are zero here; the kernel masks them to ±inf per side.
@@ -184,7 +183,7 @@ def _pad_args(x: jax.Array, k: int, block_m: int):
     m, n = x.shape
     if not 1 <= k <= n:
         raise ValueError(f"k={k} must be in [1, N={n}]")
-    pn = n % 2
+    pn = (-n) % _LANE_PAIRS
     bm = min(block_m, m)
     pm = (-m) % bm
     if pm or pn:
@@ -253,7 +252,7 @@ def streaming_quantize_outlier_kernel_call(
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec(boundaries.shape, lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))]
         + [pl.BlockSpec((bm, k), lambda i: (i, 0))] * 4,

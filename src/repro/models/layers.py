@@ -296,9 +296,9 @@ def _kv_quantize(x: jax.Array, codebook: jax.Array):
 
 
 def _kv_dequantize(packed: jax.Array, scale: jax.Array, codebook: jax.Array, dtype):
-    from repro.core.quantize import unpack_int4
+    from repro.core.quantize import lookup, unpack_int4
 
-    return (codebook[unpack_int4(packed)] * scale).astype(dtype)
+    return (lookup(codebook, unpack_int4(packed)) * scale).astype(dtype)
 
 
 def state_quantize(x: jax.Array, codebook: jax.Array):

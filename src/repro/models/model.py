@@ -21,6 +21,7 @@ params, there is no ambient/global apply config.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -217,7 +218,7 @@ def quantize_params(params, spec, calib: dict | None = None, path: str = ""):
     ``spec`` may be a :class:`QuantSpec` or (backward compat) a bare
     :class:`QLinearConfig`, which behaves as a rule-free spec. Projections a
     rule resolves to ``skip`` keep their fp weight dict. Works on stacked
-    (scan) params via vmap — note stacked projections share one path
+    (scan) params one layer at a time — note stacked projections share one path
     (``blocks/attn/wq``), so per-layer-index rules need scan_layers=False.
     """
     if isinstance(spec, QLinearConfig):
@@ -265,16 +266,13 @@ def _quantize_one(p: dict, cfg: QLinearConfig, calib: dict | None, path: str):
 
     if w.ndim < 2:
         raise ValueError(f"unexpected weight rank {w.ndim} at {path}")
-    # vmap over stacked scan axes (layers, or vlm's groups x layers)
-    if bias is None:
-        fn = lambda wi: one(wi, None)
-        for _ in range(w.ndim - 2):
-            fn = jax.vmap(fn)
-        return fn(w)
-    fn = one
+    # walk stacked scan axes (layers, or vlm's groups x layers) one slice at
+    # a time: K-Means temporaries of a whole stack at once overflow HBM at
+    # published widths, one layer's fit easily
+    fn = lambda wb: one(*wb)
     for _ in range(w.ndim - 2):
-        fn = jax.vmap(fn)
-    return fn(w, bias)
+        fn = functools.partial(jax.lax.map, fn)
+    return fn((w, bias))
 
 
 def _tap_candidates(path: str) -> tuple[str, ...]:

@@ -108,7 +108,12 @@ def make_train_step(model: Model, tc: TrainConfig) -> Callable:
         params = state["params"]
         n = tc.microbatches
         if n > 1:
-            micro = jax.tree.map(lambda x: x.reshape(n, x.shape[0] // n, *x.shape[1:]), batch)
+            # microbatch i = rows i, i+n, i+2n, ...: splitting the batch axis
+            # as (B/n, n) keeps its "batch" sharding on the B/n part, so the
+            # scanned axis is replicated (a scan may not slice a sharded axis)
+            micro = jax.tree.map(
+                lambda x: jnp.swapaxes(
+                    x.reshape(x.shape[0] // n, n, *x.shape[1:]), 0, 1), batch)
 
             def acc_body(carry, mb):
                 gacc, lacc = carry

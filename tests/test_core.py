@@ -36,6 +36,7 @@ from repro.core import (
     unpack_int4,
 )
 from repro.core.qlinear import QLinearConfig, qlinear_apply, quantize_linear
+from repro.core.quantize import lookup
 
 
 def _rand(shape, seed=0, scale=1.0):
@@ -91,6 +92,13 @@ def test_boundary_assign_equals_argmin(seed, n):
 def test_pack_unpack_roundtrip(seed, m, k2):
     idx = jax.random.randint(jax.random.PRNGKey(seed), (m, 2 * k2), 0, 16)
     np.testing.assert_array_equal(unpack_int4(pack_int4(idx)), idx)
+
+
+@pytest.mark.parametrize("n", [8, 16, 32, 256])
+def test_lookup_equals_gather(n):
+    book = jnp.sort(_rand((n,), 5))
+    idx = jax.random.randint(jax.random.PRNGKey(6), (37, 129), 0, n)
+    np.testing.assert_array_equal(lookup(book, idx), book[idx])
 
 
 def test_quantized_weight_hbm_bytes():

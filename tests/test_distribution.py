@@ -6,6 +6,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count before importing jax —
 the same pattern as the production dry-run, scaled down to a (2, 4) mesh.
 """
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -28,7 +29,8 @@ _SCRIPT = textwrap.dedent(
     from repro.train.trainer import TrainConfig, init_train_state, make_train_step
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     # rules sized for the small mesh (model axis = 4)
     rules = {
@@ -81,6 +83,6 @@ def test_sharded_train_step_matches_unsharded():
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        env={**os.environ, "PYTHONPATH": "src"},
     )
     assert "DISTRIBUTION_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-3000:]
