@@ -122,8 +122,10 @@ def main() -> None:
                   f"peak pool occupancy {st['peak_occupancy']:.0%}, "
                   f"{st['preemptions']} preemptions")
             # every engine carries a telemetry snapshot: SLO histograms
-            # (TTFT / inter-token latency) measured at the engine, plus the
-            # packed-step host/device time split
+            # (TTFT / inter-token latency) measured at the engine, the
+            # packed-step split into host work (plan, sample, commit) and
+            # device time (dispatch plus the wait for the logits), and the
+            # compiles since the engine was built
             snap = engine.snapshot()
             ttft, itl = snap["requests"]["ttft_s"], snap["requests"]["itl_s"]
             steps = snap["steps"]
@@ -132,7 +134,8 @@ def main() -> None:
                   f"ITL p50 {itl['p50'] * 1e3:.2f} ms over {itl['count']} tokens, "
                   f"step split host {steps['host_s']['mean'] * 1e3:.1f} ms / "
                   f"device {steps['device_s']['mean'] * 1e3:.1f} ms, "
-                  f"mean budget util {steps['util']['mean']:.0%}")
+                  f"mean budget util {steps['util']['mean']:.0%}, "
+                  f"{snap['counters']['serving_compiles']} compiles")
             if args.telemetry == "quality":
                 g = snap.get("gauges", {})
                 utils = [v for k, v in g.items()

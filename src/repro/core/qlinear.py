@@ -253,6 +253,7 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
                 kr.record_detect_dispatch(tier, "jnp")
 
     # ---- main branch: look-ahead LUT-GEMM over ALL activations ------------
+    # (device scopes lut_gemm / orizuru / compensate: telemetry.SCOPES)
     qa = None
     outs = None
     if route == "pallas":
@@ -261,9 +262,10 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
         # ONE fused Pallas dispatch: bucketize x in VMEM + index-GEMM.
         # Handles every weight tier (W<=4 nibble-packed, W5-8 byte-packed);
         # no QuantizedActivation and no dequantized (K, N) weight exist.
-        y = kops.lut_gemm_fused(x, p.act_codebook, p.qw,
-                                scale_mode=cfg.scale_mode,
-                                out_dtype=cfg.compute_dtype)
+        with jax.named_scope("lut_gemm"):
+            y = kops.lut_gemm_fused(x, p.act_codebook, p.qw,
+                                    scale_mode=cfg.scale_mode,
+                                    out_dtype=cfg.compute_dtype)
     else:
         if (detect_route == "pallas" and cfg.detection == "dynamic"
                 and a_nbits <= 4):
@@ -272,12 +274,15 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
             # streaming Orizuru: bucketize + dual top-k in ONE pass over the
             # activation tile — detection adds no extra HBM roundtrip. Bit-
             # identical to quantize_activation + lax.top_k (kernel contract).
-            qa, outs = kops.quantize_outlier_streaming(
-                x, p.act_codebook, k_out, cfg.scale_mode)
+            with jax.named_scope("orizuru"):
+                qa, outs = kops.quantize_outlier_streaming(
+                    x, p.act_codebook, k_out, cfg.scale_mode)
         else:
-            qa = qz.quantize_activation(x, p.act_codebook, cfg.scale_mode)
-        y = _lut_gemm_jnp(qa, p.qw, out_dtype=cfg.compute_dtype,
-                          compute_dtype=cfg.compute_dtype)
+            with jax.named_scope("lut_gemm"):
+                qa = qz.quantize_activation(x, p.act_codebook, cfg.scale_mode)
+        with jax.named_scope("lut_gemm"):
+            y = _lut_gemm_jnp(qa, p.qw, out_dtype=cfg.compute_dtype,
+                              compute_dtype=cfg.compute_dtype)
 
     # ---- outlier branch: detect, residual, compensate ----------------------
     if cfg.detection == "static_dense" and cfg.outlier_frac > 0:
@@ -287,53 +292,59 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
         # EXPERIMENTS §Perf P1); thresholds are offline (paper's OASIS-S) and
         # the mask/residual chain fuses to nothing. Decode keeps the dynamic
         # Orizuru path (sorting 1 token is free; accuracy is higher).
-        if qa is None:
-            # kernel route: the dense residual needs q(x) at EVERY channel;
-            # recompute it as the same elementwise chain (XLA fuses it into
-            # the mask/where below — no idx roundtrip, main GEMM unaffected)
-            qa = qz.quantize_activation(x, p.act_codebook, cfg.scale_mode)
-        deq = qz.dequantize_activation(qa, dtype=cfg.compute_dtype)
-        xf = x.astype(cfg.compute_dtype)
-        mask = (xf > p.thr_hi) | (xf < p.thr_lo)
-        r = jnp.where(mask, xf - deq, 0)
-        w = qz.dequantize_weight(p.qw, cfg.compute_dtype)
-        y = y + jnp.einsum("...k,kn->...n", r, w)
+        with jax.named_scope("compensate"):
+            if qa is None:
+                # kernel route: the dense residual needs q(x) at EVERY
+                # channel; recompute it as the same elementwise chain (XLA
+                # fuses it into the mask/where below — no idx roundtrip, main
+                # GEMM unaffected)
+                qa = qz.quantize_activation(x, p.act_codebook, cfg.scale_mode)
+            deq = qz.dequantize_activation(qa, dtype=cfg.compute_dtype)
+            xf = x.astype(cfg.compute_dtype)
+            mask = (xf > p.thr_hi) | (xf < p.thr_lo)
+            r = jnp.where(mask, xf - deq, 0)
+            w = qz.dequantize_weight(p.qw, cfg.compute_dtype)
+            y = y + jnp.einsum("...k,kn->...n", r, w)
     elif cfg.detection != "none" and cfg.outlier_frac > 0:
         if outs is None:
-            if cfg.detection == "dynamic" and detect_route == "pallas":
-                from repro.kernels import ops as kops
+            with jax.named_scope("orizuru"):
+                if cfg.detection == "dynamic" and detect_route == "pallas":
+                    from repro.kernels import ops as kops
 
-                # detection-only Orizuru kernel: the fused-GEMM main branch
-                # (qa is None) composes via outlier_residuals_direct below;
-                # a_bits > 4 on the jnp route lands here too (the streaming
-                # form's compare chain, like fused bucketize, tops out at A4)
-                outs = kops.topk_outlier(x.astype(jnp.float32), k_out)
-            elif cfg.detection == "dynamic":
-                outs = ol.detect_outliers_topk(x.astype(jnp.float32), k_out)
-            else:
-                outs = ol.detect_outliers_static(
-                    x.astype(jnp.float32), p.thr_lo, p.thr_hi, k_out
-                )
-        if qa is None:
-            # kernel route: q(x) at the 2k outlier channels, recomputed from
-            # the gathered values (quantization is elementwise) — bit-equal
-            # to the qa-based residual, without materializing indices
-            r = ol.outlier_residuals_direct(
-                outs, qz.token_scale(x, cfg.scale_mode), p.act_codebook,
-                mul_form=mul_form)
-        else:
-            r = ol.outlier_residuals(outs, qa)
+                    # detection-only Orizuru kernel: the fused-GEMM main
+                    # branch (qa is None) composes via outlier_residuals_direct
+                    # below; a_bits > 4 on the jnp route lands here too (the
+                    # streaming form's compare chain, like fused bucketize,
+                    # tops out at A4)
+                    outs = kops.topk_outlier(x.astype(jnp.float32), k_out)
+                elif cfg.detection == "dynamic":
+                    outs = ol.detect_outliers_topk(x.astype(jnp.float32), k_out)
+                else:
+                    outs = ol.detect_outliers_static(
+                        x.astype(jnp.float32), p.thr_lo, p.thr_hi, k_out
+                    )
         mode = cfg.comp_mode
         if mode == "auto":
             # decode-ish (few tokens): row-gather; prefill-ish: scatter+dense GEMM
             mode = "gather" if _tokens(x) <= cfg.comp_auto_tokens else "scatter"
         kr.record_comp_route(mode)
-        comp = (
-            ol.compensate_gather(r, outs, p.qw, cfg.compute_dtype)
-            if mode == "gather"
-            else ol.compensate_scatter(r, outs, p.qw, cfg.compute_dtype)
-        )
-        y = y + comp
+        with jax.named_scope("compensate"):
+            if qa is None:
+                # kernel route: q(x) at the 2k outlier channels, recomputed
+                # from the gathered values (quantization is elementwise) —
+                # bit-equal to the qa-based residual, without materializing
+                # indices
+                r = ol.outlier_residuals_direct(
+                    outs, qz.token_scale(x, cfg.scale_mode), p.act_codebook,
+                    mul_form=mul_form)
+            else:
+                r = ol.outlier_residuals(outs, qa)
+            comp = (
+                ol.compensate_gather(r, outs, p.qw, cfg.compute_dtype)
+                if mode == "gather"
+                else ol.compensate_scatter(r, outs, p.qw, cfg.compute_dtype)
+            )
+            y = y + comp
 
     if cfg.probe and nx.collecting():
         # quant-health probes (quality telemetry level only): pure reductions

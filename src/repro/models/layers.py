@@ -88,19 +88,20 @@ def norm_init(d: int, kind: str, dtype):
 
 
 def norm_apply(p, x: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    if kind == "rms":
-        y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    elif kind == "layer":
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    else:
-        raise ValueError(kind)
-    y = y * p["scale"].astype(jnp.float32)
-    if kind == "layer":
-        y = y + p["bias"].astype(jnp.float32)
-    return y.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "rms":
+            y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+        elif kind == "layer":
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+            y = (xf - mu) * jax.lax.rsqrt(var + eps)
+        else:
+            raise ValueError(kind)
+        y = y * p["scale"].astype(jnp.float32)
+        if kind == "layer":
+            y = y + p["bias"].astype(jnp.float32)
+        return y.astype(x.dtype)
 
 
 def embed_init(key, vocab: int, d: int, dtype):
@@ -128,14 +129,16 @@ def rope_apply(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """
     hd = x.shape[-1]
     half = hd // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[..., None] * freq  # (..., half)
-    # broadcast ((B,) S, 1..., half) against x's (B, S, ..., half)
-    ang = ang.reshape(*positions.shape, *([1] * (x.ndim - 3)), half)
-    sin, cos = jnp.sin(ang), jnp.cos(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1).astype(x.dtype)
+    with jax.named_scope("rope"):
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32)[..., None] * freq  # (..., half)
+        # broadcast ((B,) S, 1..., half) against x's (B, S, ..., half)
+        ang = ang.reshape(*positions.shape, *([1] * (x.ndim - 3)), half)
+        sin, cos = jnp.sin(ang), jnp.cos(ang)
+        x1, x2 = x[..., :half], x[..., half:]
+        xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
+        return jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
+                               axis=-1).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -325,37 +328,38 @@ def _cache_write(cache: dict, k, v, positions):
     ascending, and multi-token writes start ring-aligned (true for prefill
     from position 0 with C | S or S <= C — the launcher's cases).
     """
-    c = cache["slot_pos"].shape[0]
-    n_w = min(k.shape[1], c)
-    k_w, v_w = k[:, -n_w:], v[:, -n_w:]
-    pos_w = positions[-n_w:]
-    start = jnp.mod(pos_w[0], c)
+    with jax.named_scope("kv_write"):
+        c = cache["slot_pos"].shape[0]
+        n_w = min(k.shape[1], c)
+        k_w, v_w = k[:, -n_w:], v[:, -n_w:]
+        pos_w = positions[-n_w:]
+        start = jnp.mod(pos_w[0], c)
 
-    if n_w == c:
-        # full overwrite: position p+i lands in slot (p+i) % c == roll by start
-        write = lambda _, val: jnp.roll(val, start, axis=1)
-        sp = jnp.roll(pos_w, start)
-    else:
-        write = lambda buf, val: jax.lax.dynamic_update_slice(
-            buf, val, (0, start) + (0,) * (buf.ndim - 2)
-        )
-        sp = jax.lax.dynamic_update_slice(cache["slot_pos"], pos_w, (start,))
+        if n_w == c:
+            # full overwrite: position p+i lands in slot (p+i) % c == roll by start
+            write = lambda _, val: jnp.roll(val, start, axis=1)
+            sp = jnp.roll(pos_w, start)
+        else:
+            write = lambda buf, val: jax.lax.dynamic_update_slice(
+                buf, val, (0, start) + (0,) * (buf.ndim - 2)
+            )
+            sp = jax.lax.dynamic_update_slice(cache["slot_pos"], pos_w, (start,))
 
-    if "k_idx" in cache:
-        ki, ks = _kv_quantize(k_w, cache["kv_codebook"])
-        vi, vs = _kv_quantize(v_w, cache["kv_codebook"])
+        if "k_idx" in cache:
+            ki, ks = _kv_quantize(k_w, cache["kv_codebook"])
+            vi, vs = _kv_quantize(v_w, cache["kv_codebook"])
+            return cache | {
+                "k_idx": write(cache["k_idx"], ki),
+                "v_idx": write(cache["v_idx"], vi),
+                "k_scale": write(cache["k_scale"], ks),
+                "v_scale": write(cache["v_scale"], vs),
+                "slot_pos": sp,
+            }
         return cache | {
-            "k_idx": write(cache["k_idx"], ki),
-            "v_idx": write(cache["v_idx"], vi),
-            "k_scale": write(cache["k_scale"], ks),
-            "v_scale": write(cache["v_scale"], vs),
+            "k": write(cache["k"], k_w.astype(cache["k"].dtype)),
+            "v": write(cache["v"], v_w.astype(cache["v"].dtype)),
             "slot_pos": sp,
         }
-    return cache | {
-        "k": write(cache["k"], k_w.astype(cache["k"].dtype)),
-        "v": write(cache["v"], v_w.astype(cache["v"].dtype)),
-        "slot_pos": sp,
-    }
 
 
 def _cache_read(cache: dict, dtype):
@@ -439,35 +443,36 @@ def _paged_write(cache: dict, k, v, positions, ctx_lens):
     positions outside that range and are dropped via an out-of-bounds
     scatter index, so an idle slot can never corrupt another request's block.
     """
-    pages = cache["pages_k"] if "pages_k" in cache else cache["pages_k_idx"]
-    n_blocks, bs = pages.shape[0], pages.shape[1]
-    bt = cache["block_tables"]  # (B, max_blocks_per_seq)
-    b, s = positions.shape
-    blk = jnp.clip(positions // bs, 0, bt.shape[1] - 1)
-    block_id = jnp.take_along_axis(bt, blk, axis=1)  # (B, S)
-    valid = (positions >= 0) & (positions < ctx_lens[:, None]) & (block_id >= 0)
-    dest = jnp.where(valid, block_id * bs + positions % bs, n_blocks * bs)
+    with jax.named_scope("kv_write"):
+        pages = cache["pages_k"] if "pages_k" in cache else cache["pages_k_idx"]
+        n_blocks, bs = pages.shape[0], pages.shape[1]
+        bt = cache["block_tables"]  # (B, max_blocks_per_seq)
+        b, s = positions.shape
+        blk = jnp.clip(positions // bs, 0, bt.shape[1] - 1)
+        block_id = jnp.take_along_axis(bt, blk, axis=1)  # (B, S)
+        valid = (positions >= 0) & (positions < ctx_lens[:, None]) & (block_id >= 0)
+        dest = jnp.where(valid, block_id * bs + positions % bs, n_blocks * bs)
 
-    def scatter(pool, vals):
-        flat = pool.reshape(n_blocks * bs, *pool.shape[2:])
-        flat = flat.at[dest.reshape(-1)].set(
-            vals.reshape(b * s, *vals.shape[2:]), mode="drop"
-        )
-        return flat.reshape(pool.shape)
+        def scatter(pool, vals):
+            flat = pool.reshape(n_blocks * bs, *pool.shape[2:])
+            flat = flat.at[dest.reshape(-1)].set(
+                vals.reshape(b * s, *vals.shape[2:]), mode="drop"
+            )
+            return flat.reshape(pool.shape)
 
-    if "pages_k_idx" in cache:
-        ki, ks = _kv_quantize(k, cache["kv_codebook"])
-        vi, vs = _kv_quantize(v, cache["kv_codebook"])
+        if "pages_k_idx" in cache:
+            ki, ks = _kv_quantize(k, cache["kv_codebook"])
+            vi, vs = _kv_quantize(v, cache["kv_codebook"])
+            return cache | {
+                "pages_k_idx": scatter(cache["pages_k_idx"], ki),
+                "pages_v_idx": scatter(cache["pages_v_idx"], vi),
+                "pages_k_scale": scatter(cache["pages_k_scale"], ks),
+                "pages_v_scale": scatter(cache["pages_v_scale"], vs),
+            }
         return cache | {
-            "pages_k_idx": scatter(cache["pages_k_idx"], ki),
-            "pages_v_idx": scatter(cache["pages_v_idx"], vi),
-            "pages_k_scale": scatter(cache["pages_k_scale"], ks),
-            "pages_v_scale": scatter(cache["pages_v_scale"], vs),
+            "pages_k": scatter(cache["pages_k"], k.astype(pages.dtype)),
+            "pages_v": scatter(cache["pages_v"], v.astype(pages.dtype)),
         }
-    return cache | {
-        "pages_k": scatter(cache["pages_k"], k.astype(pages.dtype)),
-        "pages_v": scatter(cache["pages_v"], v.astype(pages.dtype)),
-    }
 
 
 def _paged_attend(cache: dict, q, q_pos, softcap, window: int = 0):
@@ -489,7 +494,7 @@ def _paged_attend(cache: dict, q, q_pos, softcap, window: int = 0):
     quantized = "pages_k_idx" in cache
     # named unconditionally (telemetry-independent) so XLA profiles line up
     # with the serving timeline names in every mode — and the jaxpr is the
-    # same whether telemetry is on or off
+    # same whether telemetry is on or off (telemetry.SCOPES lists them all)
     with jax.named_scope("paged_attention"):
         if _paged_kernel_enabled():
             from repro.kernels.ops import should_interpret
@@ -637,15 +642,16 @@ def mlp_init(key, d: int, d_ff: int, act_fn: str, dtype):
 
 def mlp_apply(p, x: jax.Array, act_fn: str, layer_tag: str = "mlp") -> jax.Array:
     hidden = dense_apply(p["wi"], x, f"{layer_tag}.wi")
-    if act_fn in ("silu", "gelu"):
-        gate, up = jnp.split(hidden, 2, axis=-1)
-        act = jax.nn.silu(gate) if act_fn == "silu" else jax.nn.gelu(gate)
-        hidden = act * up
-    elif act_fn == "relu2":
-        hidden = jnp.square(jax.nn.relu(hidden))
-    elif act_fn == "gelu_plain":
-        hidden = jax.nn.gelu(hidden)
-    else:
-        raise ValueError(act_fn)
-    hidden = constrain(hidden, "batch", "seq", "d_ff")
+    with jax.named_scope("mlp_act"):
+        if act_fn in ("silu", "gelu"):
+            gate, up = jnp.split(hidden, 2, axis=-1)
+            act = jax.nn.silu(gate) if act_fn == "silu" else jax.nn.gelu(gate)
+            hidden = act * up
+        elif act_fn == "relu2":
+            hidden = jnp.square(jax.nn.relu(hidden))
+        elif act_fn == "gelu_plain":
+            hidden = jax.nn.gelu(hidden)
+        else:
+            raise ValueError(act_fn)
+        hidden = constrain(hidden, "batch", "seq", "d_ff")
     return dense_apply(p["wd"], hidden, f"{layer_tag}.wd")

@@ -116,20 +116,22 @@ def _block_apply(p, x, cfg: ModelConfig, positions, cache):
 
 
 def _embed_in(params, cfg: ModelConfig, tokens, positions):
-    x = params["embed"]["table"][tokens].astype(jnp.dtype(cfg.compute_dtype))
-    if cfg.pos_embed == "sinusoidal":
-        pe = L.sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
-        x = x + (pe if positions.ndim == 2 else pe[None])  # (B,S,d) | (1,S,d)
-    return constrain(x, "batch", "seq_sp", "d_model")
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"][tokens].astype(jnp.dtype(cfg.compute_dtype))
+        if cfg.pos_embed == "sinusoidal":
+            pe = L.sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
+            x = x + (pe if positions.ndim == 2 else pe[None])  # (B,S,d) | (1,S,d)
+        return constrain(x, "batch", "seq_sp", "d_model")
 
 
 def _logits_out(params, cfg: ModelConfig, x):
-    x = L.norm_apply(params["norm_f"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["table"].astype(x.dtype).T
-    else:
-        logits = L.dense_apply(params["head"], x)
-    return constrain(logits.astype(jnp.float32), "batch", "seq", "vocab")
+    with jax.named_scope("logits"):
+        x = L.norm_apply(params["norm_f"], x, cfg.norm)
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["table"].astype(x.dtype).T
+        else:
+            logits = L.dense_apply(params["head"], x)
+        return constrain(logits.astype(jnp.float32), "batch", "seq", "vocab")
 
 
 def apply(params, cfg: ModelConfig, tokens: jax.Array, *, positions=None, caches=None, last_only: bool = False, return_hidden_only: bool = False):
@@ -181,7 +183,10 @@ def apply(params, cfg: ModelConfig, tokens: jax.Array, *, positions=None, caches
             new_caches = None
         else:
             xs = params["blocks"] if caches is None else (params["blocks"], caches)
-            x, new_caches = jax.lax.scan(body, x, xs)
+            # the scan's own work (each layer's weights and pools sliced in,
+            # pools written back) is named apart from the layers' scopes
+            with jax.named_scope("layer_scan"):
+                x, new_caches = jax.lax.scan(body, x, xs)
     else:
         new_caches = []
         for i, p in enumerate(params["blocks"]):

@@ -80,7 +80,9 @@ class ServeConfig:
     # probes of core/numerics — sampled probed packed steps, drift alarms,
     # shadow-reference quality checks; the only level allowed to recompile),
     # "off" (null object: zero per-token work and an untouched packed-step
-    # jaxpr), or a TelemetryConfig for fence/ring/sampling knobs
+    # jaxpr), or a TelemetryConfig for ring/sampling knobs. Every level
+    # compiles the same packed step, with the same device scopes; the step's
+    # host/device split comes from the scheduler's wait for its logits
     telemetry: object = "metrics"
 
     @classmethod
@@ -177,6 +179,7 @@ class ServingEngine:
         self.model, self.sc, self.slots = model, sc, batch_slots
         self.params = params
         self.telemetry = make_telemetry(sc.telemetry)
+        self.telemetry.watch_compiles(self)
         policies = model.cache_policies()
         self.paged = sc.paged and policies is not None
         if self.paged and sc.speculative is not None \

@@ -147,6 +147,23 @@ class Request:
         return out + [pad] * (self.max_new_tokens - len(out))
 
 
+@dataclasses.dataclass
+class _StepPlan:
+    """One packed step's plan: who runs, and where their tokens sit in the
+    grid (``pos`` -1 = padded cell)."""
+
+    decoders: list[Request]
+    segments: list[tuple[Request, int, int]]  # (request, start, n) prefill runs
+    drafts: dict[int, list[int]]  # rid -> proposed draft tokens
+    draft_s: float  # seconds spent proposing them
+    bt: np.ndarray  # (slots, max_blocks) block tables
+    slot_ids: np.ndarray  # (rows,)
+    pos: np.ndarray  # (rows, seg_width)
+    tok: np.ndarray  # (rows, seg_width)
+    verify_cells: dict[int, list]  # rid -> its decode / verify cells
+    last_cell: dict[int, tuple[int, int]]  # rid -> its last prefill cell
+
+
 class Scheduler:
     """Owns the block pool, the allocator, and the single jitted packed step.
 
@@ -353,6 +370,12 @@ class Scheduler:
             help="high-water LIVE (non-freed) blocks held by any one request "
                  "— bounded by ceil(window/block_size)+1 under windowed_paged")
         self._packed_fn = jax.jit(make_packed_fn(model))
+        # profiles of the packed step are read by its named scopes
+        # (telemetry.scope_map), which live in the executable's metadata;
+        # JAX's persistent compile cache leaves metadata out of its key by
+        # default, and would hand back an executable named by whichever
+        # revision of the program compiled it first
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
         self._copy_fn = jax.jit(copy_blocks)
         if self._rec:
             self._zero_fn = jax.jit(zero_state_slot)
@@ -447,9 +470,10 @@ class Scheduler:
         True while work remains — online drivers (bench_serving) interleave
         ``submit`` between steps.
         """
-        admitted = self._refill_slots()
-        for r in [r for r in self._running if r.done]:
-            self._finish(r, results)
+        with self.telemetry.annotate("sched.admit", step=self._step_i):
+            admitted = self._refill_slots()
+            for r in [r for r in self._running if r.done]:
+                self._finish(r, results)
         if self._running:
             self._packed_once(results)
             return True
@@ -550,13 +574,77 @@ class Scheduler:
         then prefill segments FCFS over the remaining rows (a request's next
         unwritten context tokens, packed ``seg_width`` per row, clipped to
         the rows that fit; large prompts span several steps).
+
+        Telemetry spans, each with ``step=<packed-step number>``, under one
+        ``packed_step`` span: ``sched.plan`` (reservation, copy-on-write,
+        grid fill), ``sched.dispatch`` (transfers + the jitted call),
+        ``sched.wait`` (blocks on the logits: the device's step),
+        ``sched.sample`` (reading and sampling the logits) and
+        ``sched.commit`` (bookkeeping, finishing, probes, the step record).
         """
+        tel = self.telemetry
+        step = self._step_i
+        with tel.annotate("packed_step", step=step):
+            t_host0 = tel.now()
+            blocks_alloc0 = self.allocator.blocks_allocated
+            blocks_freed0 = self.allocator.blocks_freed
+            cow0 = self._c["cow_copies"].value
+            with tel.annotate("sched.plan", step=step):
+                plan = self._plan()
+                if plan is None:
+                    return
+                # per-row horizon (all-pad rows stay 0)
+                ctx = plan.pos.max(axis=1) + 1
+            # quality level: 1 in quality_sample_every steps runs the PROBED
+            # packed fn (step 0 included, so short smokes populate every
+            # gauge); all other steps — and every other level — dispatch the
+            # untouched packed step
+            probe_now = (self._probe_fn is not None and
+                         step % tel.cfg.quality_sample_every == 0)
+            probes = None
+            with tel.annotate("sched.dispatch", step=step):
+                t_dispatch = tel.now()
+                args = (self.params, self.pools, jnp.asarray(plan.bt),
+                        jnp.asarray(plan.slot_ids), jnp.asarray(plan.pos),
+                        jnp.asarray(ctx), jnp.asarray(plan.tok))
+                if probe_now:
+                    self.pools, logits, extras, probes = self._probe_fn(*args)
+                else:
+                    self.pools, logits, extras = self._packed_fn(*args)
+            with tel.annotate("sched.wait", step=step):
+                # times the device's step apart from the host's work; sampling
+                # blocks on the logits anyway, so this adds no dispatch (only
+                # the first sample's small dispatches no longer queue behind
+                # the step)
+                jax.block_until_ready(logits)
+            t_done = tel.now()
+            self._c_target_time.add(t_done - t_dispatch)
+            with tel.annotate("sched.sample", step=step):
+                picks, am, shadow_args = self._sample_step(plan, logits)
+            with tel.annotate("sched.commit", step=step):
+                n_cells = self._commit(plan, results, picks, am, extras, probes,
+                                       shadow_args)
+                if tel.enabled:
+                    dec_rows = len(plan.decoders) * self._dec_rows
+                    tel.step_record(
+                        host_s=(t_dispatch - t_host0 - plan.draft_s) + (tel.now() - t_done),
+                        device_s=t_done - t_dispatch,
+                        cells=n_cells, budget=self.token_budget,
+                        decode_rows=0 if self.spec else dec_rows,
+                        verify_rows=dec_rows if self.spec else 0,
+                        prefill_rows=sum(-(-n // self.seg_width)
+                                         for _, _, n in plan.segments),
+                        blocks_allocated=self.allocator.blocks_allocated - blocks_alloc0,
+                        blocks_freed=self.allocator.blocks_freed - blocks_freed0,
+                        blocks_copied=self._c["cow_copies"].value - cow0,
+                    )
+
+    def _plan(self) -> _StepPlan | None:
+        """Reserve blocks (growth, copy-on-write; may preempt), propose
+        drafts, and fill the grid. None when preemption left nothing
+        running."""
         S = self.seg_width
         tel = self.telemetry
-        t_host0 = tel.now()
-        blocks_alloc0 = self.allocator.blocks_allocated
-        blocks_freed0 = self.allocator.blocks_freed
-        cow0 = self._c["cow_copies"].value
         while True:
             # decode reservation: guarantee blocks for every incoming token
             # (may preempt — victims leave self._running, incl. prefilling)
@@ -564,7 +652,7 @@ class Scheduler:
                 if r.state is RequestState.RUNNING and r.decoding:
                     self._grow(r, self._k_for(r) + 1)
             if not self._running:
-                return
+                return None
             decoders = [r for r in self._running if r.decoding]
             segments: list[tuple[Request, int, int]] = []  # (request, start, n)
             rows_left = self.rows - len(decoders) * self._dec_rows
@@ -628,72 +716,76 @@ class Scheduler:
             verify_cells[r.rid] = fill([r.next_token] + drafts.get(r.rid, []),
                                        len(r.context), r.slot)
         last_cell: dict[int, tuple[int, int]] = {}
-        n_prefill = 0
         for r, start, n in segments:
             last_cell[r.rid] = fill(r.context[start : start + n], start,
                                     r.slot)[-1]
-            n_prefill += n
-        ctx = pos.max(axis=1) + 1  # per-row horizon (all-pad rows stay 0)
+        return _StepPlan(decoders, segments, drafts, draft_dt, bt, slot_ids, pos,
+                         tok, verify_cells, last_cell)
 
-        # quality level: 1 in quality_sample_every steps runs the PROBED
-        # packed fn (step 0 included, so short smokes populate every gauge);
-        # all other steps — and every other level — dispatch the untouched
-        # packed step
-        probe_now = (self._probe_fn is not None and
-                     self._step_i % tel.cfg.quality_sample_every == 0)
-        probes = None
-        t_dispatch = tel.now()
-        with tel.annotate("packed_step"):
-            args = (self.params, self.pools, jnp.asarray(bt),
-                    jnp.asarray(slot_ids), jnp.asarray(pos), jnp.asarray(ctx),
-                    jnp.asarray(tok))
-            if probe_now:
-                self.pools, logits, extras, probes = self._probe_fn(*args)
-            else:
-                self.pools, logits, extras = self._packed_fn(*args)
-            if tel.fence:  # exact host/device split on async backends
-                jax.block_until_ready(logits)
-        t_done = tel.now()
-        self._c_target_time.add(t_done - t_dispatch)
+    def _sample_step(self, plan: _StepPlan, logits):
+        """Read this step's tokens from its logits: each plain decoder's
+        next token, the first token of each request whose prompt finished
+        this step, every verify argmax under speculation, and the quality
+        level's shadow-probe logits. Returns (picks by rid, verify argmaxes
+        or None, shadow-probe args or None)."""
+        decoders, verify_cells = plan.decoders, plan.verify_cells
+        picks: dict[int, int] = {}
+        am = None
+        if self.spec is not None and decoders:
+            # one device->host transfer of every verify argmax
+            am = np.asarray(jnp.argmax(logits, axis=-1))
+        shadow_args = None
+        if (self._quality is not None and decoders and
+                self._step_i % self.telemetry.cfg.quality_shadow_every == 0):
+            # deepest committed context = most decode positions to audit;
+            # the first verify cell's logits condition on context +
+            # [next_token] — the prefix the shadow forward replays
+            r = max(decoders, key=lambda q: len(q.context))
+            rw0, cc0 = verify_cells[r.rid][0]
+            shadow_args = (r, np.asarray(logits[rw0, cc0], np.float32),
+                           len(r.context) + 1)
+        if self.spec is None:
+            for r in decoders:
+                rw, cc = verify_cells[r.rid][0]
+                picks[r.rid] = self._sample(logits[rw, cc], r)
+        for r, start, n in plan.segments:
+            if start + n >= len(r.context) and r.next_token is None:
+                # the prompt's real last token was in this step: its logits
+                # cell is the first sampled token (a re-admitted preemption
+                # keeps its already-decided next_token instead)
+                rw, col = plan.last_cell[r.rid]
+                picks[r.rid] = self._sample(logits[rw, col], r)
+        return picks, am, shadow_args
 
+    def _commit(self, plan: _StepPlan, results, picks, am, extras, probes,
+                shadow_args) -> int:
+        """Apply one step's sampled tokens: decode / verify bookkeeping,
+        prefill progress and first tokens, prefix-block registration, window
+        release, finishing, and the quality probes. Returns the step's
+        valid cells."""
+        tel = self.telemetry
+        decoders, segments = plan.decoders, plan.segments
+        n_cells = int((plan.pos >= 0).sum())
         st = self._c
-        n_cells = int((pos >= 0).sum())
         st["packed_steps"].add()
         st["packed_tokens"].add(n_cells)
-        st["prefill_tokens"].add(n_prefill)
+        st["prefill_tokens"].add(sum(n for _, _, n in segments))
         st["prefill_chunks"].add(len(segments))
         if decoders:
             st["decode_steps"].add()
         if decoders and segments:
             st["mixed_steps"].add()
-
-        if self.spec is not None and decoders:
-            # one device->host transfer of every verify argmax
-            am = np.asarray(jnp.argmax(logits, axis=-1))
-        shadow_pick = None
-        shadow_args = None
-        if (self._quality is not None and decoders and
-                self._step_i % tel.cfg.quality_shadow_every == 0):
-            # deepest committed context = most decode positions to audit
-            shadow_pick = max(decoders, key=lambda q: len(q.context))
         for r in decoders:
-            cells = verify_cells[r.rid]
-            if r is shadow_pick:
-                # first verify cell's logits condition on context +
-                # [next_token] — the prefix the shadow forward replays
-                rw0, cc0 = cells[0]
-                shadow_args = (r, np.asarray(logits[rw0, cc0], np.float32),
-                               len(r.context) + 1)
+            cells = plan.verify_cells[r.rid]
             r.context.append(r.next_token)
             r.prefilled += 1  # the segment's first cell wrote it to the cache
             if self.spec is None:
-                rw, cc = cells[0]
-                r.next_token = self._sample(logits[rw, cc], r)
+                r.next_token = picks[r.rid]
                 r.generated.append(r.next_token)
                 st["decode_slot_tokens"].add()
                 tel.tokens_committed(r.rid, 1)
                 continue
-            d = drafts.get(r.rid, [])
+            d = plan.drafts.get(r.rid, [])
             committed = greedy_verify([int(am[rr, cc]) for rr, cc in cells], d,
                                       r.eos_id)
             # all committed-but-last tokens have valid KV already in the
@@ -733,12 +825,8 @@ class Scheduler:
         for r, start, n in segments:
             r.prefilled = start + n
             tel.request_event(r.rid, "prefill_chunk", start=start, n=n)
-            if r.decoding and r.next_token is None:
-                # the prompt's real last token was in this step: its logits
-                # cell is the first sampled token (a re-admitted preemption
-                # keeps its already-decided next_token instead)
-                rw, col = last_cell[r.rid]
-                r.next_token = self._sample(logits[rw, col], r)
+            if r.rid in picks:
+                r.next_token = picks[r.rid]
                 r.generated.append(r.next_token)
                 tel.first_token(r.rid)
         for r in self._running:
@@ -757,19 +845,7 @@ class Scheduler:
         if shadow_args is not None:
             self._shadow_probe(*shadow_args)
         self._step_i += 1
-        if tel.enabled:
-            dec_rows = len(decoders) * self._dec_rows
-            tel.step_record(
-                host_s=(t_dispatch - t_host0 - draft_dt) + (tel.now() - t_done),
-                device_s=t_done - t_dispatch,
-                cells=n_cells, budget=self.token_budget,
-                decode_rows=0 if self.spec else dec_rows,
-                verify_rows=dec_rows if self.spec else 0,
-                prefill_rows=sum(-(-n // S) for _, _, n in segments),
-                blocks_allocated=self.allocator.blocks_allocated - blocks_alloc0,
-                blocks_freed=self.allocator.blocks_freed - blocks_freed0,
-                blocks_copied=self._c["cow_copies"].value - cow0,
-            )
+        return n_cells
 
     def _blocks_for(self, n_tokens: int) -> int:
         """Blocks to reserve for an ``n_tokens`` context: zero when no layer

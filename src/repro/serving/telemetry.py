@@ -1,28 +1,40 @@
 """Serving telemetry: typed metrics, request lifecycle timelines, step records.
 
-One :class:`Telemetry` object per engine owns four things:
+One :class:`Telemetry` object per engine owns five things:
 
 1. a **typed metrics registry** — :class:`Counter`, :class:`Gauge` (settable
    or callback-backed), and fixed-bucket :class:`Histogram` (log- or
    linear-spaced). It absorbs the scheduler's old ad-hoc ``stats`` dict: the
    counters ARE the stats now, and ``Scheduler.stats`` /
    ``ServingEngine.stats`` rebuild the legacy keys from the registry.
+   ``serving_compiles`` / ``serving_compile_s`` count the backend compiles
+   of the process while the engine lives (:meth:`Telemetry.watch_compiles`),
+   so a recompile in steady state shows in every snapshot.
 2. a **per-request lifecycle timeline** — enqueue → admit (with prefix-hit
    size) → prefill chunks → first token → verify rounds / rollbacks →
    preempt / finish, with wall times, so TTFT, inter-token latency, queue
    wait, and end-to-end latency percentiles come from the engine itself
-   rather than a bench harness. TTFT/ITL/latency *histograms* update at the
-   default ``metrics`` level; full per-request event lists are kept only
-   under ``trace``.
+   rather than a bench harness. TTFT/ITL/latency *histograms* and each
+   completed request's submit / admit / first-chunk / first-token stamps
+   (the bounded ``completed`` deque) are kept at every enabled level; full
+   per-request event lists only under ``trace``.
 3. a **bounded ring buffer of per-packed-step records** — budget
    utilization, rows by kind (decode / verify / prefill), blocks
-   allocated / freed / copied this step, and the host-prep vs device time
-   split (device time is dispatch wall time; pass ``fence=True`` to
-   ``block_until_ready`` the step output so the split is exact on async
-   backends).
-4. **exporters** — :meth:`Telemetry.snapshot` (JSON-able dict of every
+   allocated / freed / copied this step, and the host vs device time split:
+   ``device_s`` is the dispatch plus the wait for the step's logits (the
+   scheduler always blocks on them before sampling), ``host_s`` the rest of
+   the step's host work (planning, sampling, bookkeeping).
+4. **spans and scopes** — :meth:`Telemetry.annotate` opens a
+   ``jax.profiler.TraceAnnotation`` (with keyword args, e.g. the packed-step
+   number) so profiles show what the host was doing on the device's clock;
+   the scheduler's phase spans (``packed_step`` over ``sched.plan`` →
+   ``sched.dispatch`` → ``sched.wait`` → ``sched.sample`` → ``sched.commit``,
+   after ``sched.admit``) go through it. Device work carries unconditional
+   ``jax.named_scope`` names (:data:`SCOPES`); :func:`scope_map` maps a
+   compiled module's instruction names to them.
+5. **exporters** — :meth:`Telemetry.snapshot` (JSON-able dict of every
    metric plus derived percentiles) and :meth:`Telemetry.export_chrome_trace`
-   (Chrome/Perfetto trace-event JSON: packed steps and draft dispatches as
+   (Chrome/Perfetto trace-event JSON: packed steps and named spans as
    slices on an engine lane, one lane per request with queued / prefill /
    decode phases and instant events — load it at ``ui.perfetto.dev``).
 
@@ -30,9 +42,10 @@ Levels (``ServeConfig.telemetry``): ``"off"`` is a null object — every method
 is a no-op, no per-token work, zero device dispatches, and the packed step's
 jaxpr is untouched (telemetry never wraps traced code; only host-side
 ``jax.profiler.TraceAnnotation`` spans are emitted, and only when enabled).
-``"metrics"`` (default) keeps counters, gauges, histograms, and the step
-ring. ``"trace"`` additionally records per-request event timelines and named
-spans for the Perfetto export. ``"quality"`` is trace plus the quantization-
+``"metrics"`` (default) keeps counters, gauges, histograms, completed-request
+stamps and the step ring; its spans are bare profiler annotations.
+``"trace"`` additionally records per-request event timelines and every span
+in a ring for the Perfetto export. ``"quality"`` is trace plus the quantization-
 numerics observability layer (``core/numerics``): the scheduler swaps in a
 PROBED packed step on sampled steps (1 in ``quality_sample_every``), so —
 unlike every other level — quality is allowed to retrace/recompile; the
@@ -58,12 +71,13 @@ import math
 import pathlib
 import re
 import time
+import weakref
 from collections import deque
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "StreamingStats",
     "TelemetryConfig", "Telemetry", "NullTelemetry", "make_telemetry",
-    "log_buckets", "linear_buckets",
+    "log_buckets", "linear_buckets", "SCOPES", "scope_map",
 ]
 
 
@@ -339,10 +353,8 @@ class TelemetryConfig:
     ``"trace"`` (adds per-request event timelines + named spans for the
     Perfetto export), or ``"quality"`` (trace + the quantization-numerics
     probes of ``core/numerics``; the only level allowed to recompile).
-    ``fence=True`` blocks on the packed step's output so the host/device
-    time split is exact (adds a sync, never a dispatch). ``step_ring``
-    bounds the per-step record buffer; ``max_requests`` bounds completed
-    request timelines kept under trace.
+    ``step_ring`` bounds the per-step record buffer (and the span ring under
+    trace); ``max_requests`` bounds the completed requests kept.
 
     Quality knobs (ignored below level quality): ``quality_sample_every``
     probes 1 in N packed steps (step 0 always probes, so short smokes
@@ -352,7 +364,6 @@ class TelemetryConfig:
     """
 
     level: str = "metrics"
-    fence: bool = False
     step_ring: int = 512
     max_requests: int = 2048
     quality_sample_every: int = 16
@@ -394,6 +405,7 @@ class _RequestTrace:
     t_enqueue: float
     n_prompt: int = 0
     t_admit: float | None = None
+    t_first_chunk: float | None = None  # first prefill chunk committed
     t_first_token: float | None = None
     t_finish: float | None = None
     t_last_token: float | None = None
@@ -435,10 +447,6 @@ class Telemetry:
     def quality(self) -> bool:
         return self.cfg.level == "quality"
 
-    @property
-    def fence(self) -> bool:
-        return self.cfg.fence
-
     def now(self) -> float:
         return self._clock()
 
@@ -451,16 +459,36 @@ class Telemetry:
     def histogram(self, name: str, bounds=None, help: str = "") -> Histogram:
         return self.registry.histogram(name, bounds, help)
 
-    def annotate(self, name: str):
-        """Host-side ``jax.profiler.TraceAnnotation`` span (so XLA profiles
-        line up with our timeline names) that ALSO lands in the span deque
-        under trace level. Never wraps traced code — the jaxpr is untouched."""
+    def annotate(self, name: str, **args):
+        """Host-side ``jax.profiler.TraceAnnotation`` span named ``name``,
+        carrying ``args`` (e.g. ``step=``) as stats of the profiler event, so
+        a profile shows what the host was doing on the device's clock. Under
+        trace level it ALSO lands in the span ring. Never wraps traced code —
+        the jaxpr is untouched."""
         import jax.profiler
 
-        ann = jax.profiler.TraceAnnotation(name)
+        ann = jax.profiler.TraceAnnotation(name, **args)
         if not self.tracing:
             return ann
-        return _Span(self, name, ann)
+        return _Span(self, name, ann, args)
+
+    def watch_compiles(self, owner) -> None:
+        """Count the process's backend compiles in ``serving_compiles`` and
+        their seconds in ``serving_compile_s`` while ``owner`` (the engine)
+        lives: a jax.monitoring listener that holds this object weakly and
+        is unregistered when ``owner`` is collected."""
+        import jax.monitoring
+
+        ref = weakref.ref(self)
+
+        def on_duration(event, duration, **_):
+            tel = ref()
+            if tel is not None and event == _COMPILE_EVENT:
+                tel.c_compiles.add()
+                tel.c_compile_s.add(duration)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        weakref.finalize(owner, _unlisten, on_duration)
 
     def reset(self) -> None:
         """Zero every metric and drop buffered timelines (benchmarks call
@@ -483,6 +511,10 @@ class Telemetry:
     def _mk_serving_metrics(self) -> None:
         """Pre-register the serving metric families so a snapshot taken
         before traffic still shows the full (zeroed) schema."""
+        self.c_compiles = self.counter(
+            "serving_compiles", "backend compiles while the engine lives")
+        self.c_compile_s = self.counter(
+            "serving_compile_s", "seconds of those backend compiles")
         self.hist_ttft = self.histogram(
             "serving_ttft_s", log_buckets(1e-4, 1e3),
             "enqueue -> first sampled token, seconds")
@@ -497,10 +529,10 @@ class Telemetry:
             "enqueue -> admission, seconds")
         self.hist_step_host = self.histogram(
             "serving_step_host_s", log_buckets(1e-6, 1e2),
-            "host-side packed-step prep per step, seconds")
+            "packed-step host work (plan, sample, commit) per step, seconds")
         self.hist_step_device = self.histogram(
             "serving_step_device_s", log_buckets(1e-6, 1e2),
-            "packed-step dispatch (device when fenced) per step, seconds")
+            "packed-step dispatch plus the wait for its logits, seconds")
         self.hist_step_util = self.histogram(
             "serving_step_util", linear_buckets(0.0, 1.0, 20),
             "valid cells / token budget per packed step")
@@ -532,11 +564,14 @@ class Telemetry:
 
     def request_event(self, rid: int, name: str, **args) -> None:
         """Trace-level timeline event (prefill_chunk, verify_round, rollback,
-        cow, ...); a no-op at the metrics level."""
-        if not self.tracing:
-            return
+        cow, ...); at the metrics level only the first prefill chunk's time
+        is kept."""
         tr = self._trace(rid)
-        if tr is not None and tr.events is not None:
+        if tr is None:
+            return
+        if name == "prefill_chunk" and tr.t_first_chunk is None:
+            tr.t_first_chunk = self.now()
+        if tr.events is not None:
             tr.events.append((self.now(), name, args))
 
     def first_token(self, rid: int) -> None:
@@ -590,8 +625,7 @@ class Telemetry:
         self.hist_e2e.observe(t - tr.t_enqueue)
         if tr.events is not None:
             tr.events.append((t, "finish", {"generated": tr.n_generated}))
-        if self.tracing:
-            self.completed.append(tr)
+        self.completed.append(tr)
 
     # ------------------------------------------------------------ step records
     def step_record(self, *, host_s: float, device_s: float, cells: int,
@@ -621,7 +655,7 @@ class Telemetry:
         snap["level"] = self.cfg.level
         snap["requests"] = {
             "live": len(self._live),
-            "completed_traced": len(self.completed),
+            "completed": len(self.completed),
             "ttft_s": self.hist_ttft.summary(),
             "itl_s": self.hist_itl.summary(),
             "e2e_s": self.hist_e2e.summary(),
@@ -640,8 +674,8 @@ class Telemetry:
         """Write a Chrome/Perfetto trace-event JSON file and return its path.
 
         Lanes: pid 0 ("engine") carries packed-step slices (from the step
-        ring) on tid 0 and named spans (draft scan/catch-up, trace level) on
-        tid 1; pid 1 ("requests") gives every traced request its own tid with
+        ring) on tid 0 and named spans (scheduler phases, draft scan/catch-up;
+        trace level) on tid 1; pid 1 ("requests") gives every traced request its own tid with
         queued/prefill/decode phase slices and instant events; pid 2
         ("quality") renders the numerics counter tracks ("C" events — one
         track per metric, so quantization quality and latency share a
@@ -669,9 +703,10 @@ class Telemetry:
             ev.append({"ph": "X", "pid": 0, "tid": 0, "name": "packed_step",
                        "ts": t1 - dur, "dur": dur,
                        "args": {k: v for k, v in s.items() if k != "t"}})
-        for name, t_start, dur_s in self.spans:
+        for name, t_start, dur_s, args in self.spans:
             ev.append({"ph": "X", "pid": 0, "tid": 1, "name": name,
-                       "ts": (t_start - self._t0) * us, "dur": dur_s * us})
+                       "ts": (t_start - self._t0) * us, "dur": dur_s * us,
+                       "args": args})
         for tr in (*self.completed, *self._live.values()):
             tid = tr.rid
             ev.append({"ph": "M", "pid": 1, "tid": tid, "name": "thread_name",
@@ -747,10 +782,10 @@ def _promval(v) -> str:
 class _Span:
     """Context manager pairing a jax TraceAnnotation with a span record."""
 
-    __slots__ = ("tel", "name", "ann", "t0")
+    __slots__ = ("tel", "name", "ann", "args", "t0")
 
-    def __init__(self, tel: Telemetry, name: str, ann):
-        self.tel, self.name, self.ann = tel, name, ann
+    def __init__(self, tel: Telemetry, name: str, ann, args: dict):
+        self.tel, self.name, self.ann, self.args = tel, name, ann, args
 
     def __enter__(self):
         self.ann.__enter__()
@@ -758,8 +793,58 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        self.tel.spans.append((self.name, self.t0, self.tel.now() - self.t0))
+        self.tel.spans.append((self.name, self.t0, self.tel.now() - self.t0,
+                               self.args))
         return self.ann.__exit__(*exc)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _unlisten(callback) -> None:
+    import jax.monitoring
+
+    try:
+        jax.monitoring.unregister_event_duration_listener(callback)
+    except AssertionError:  # listeners were cleared meanwhile
+        pass
+
+
+# ---------------------------------------------------------------------------
+# device scopes
+# ---------------------------------------------------------------------------
+
+# The jax.named_scope names the serving program puts on the packed step's
+# device work, from the scan over layers down to the kernels (models/layers,
+# models/transformer, core/qlinear). Unconditional: every telemetry level
+# compiles the same program.
+SCOPES = (
+    "layer_scan",  # the scan over layers: each layer's weights and pools in, pools out
+    "embed",  # token embedding
+    "norm",  # RMS / layer norms
+    "rope",  # rotary position embedding
+    "kv_write",  # int4 K-Means KV quantize + the paged pool write
+    "paged_attention",  # attention through the block tables
+    "lut_gemm",  # the projections' main branch: activation quantize + index GEMM
+    "orizuru",  # outlier detection
+    "compensate",  # outlier residuals, weight-row gather / scatter, index unpack
+    "mlp_act",  # the MLP's gated activation
+    "logits",  # final norm and the vocabulary projection
+)
+
+_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=.*?\bop_name="([^"]*)"', re.M)
+
+
+def scope_map(hlo_text: str) -> dict[str, str]:
+    """HLO instruction name -> the program's scopes (:data:`SCOPES`) in its
+    ``op_name`` metadata, outer to inner, joined by "/" ("" under none), over
+    a compiled module's text (``Compiled.as_text()``). The names are those of
+    the device events of a profile of that module, so a profile's device
+    time can be read by program scope. Instructions without an ``op_name``
+    are left out."""
+    return {m.group(1): "/".join(p for p in m.group(2).split("/") if p in SCOPES)
+            for m in _OP_NAME.finditer(hlo_text)}
 
 
 class NullTelemetry:
@@ -772,7 +857,6 @@ class NullTelemetry:
     enabled = False
     tracing = False
     quality = False
-    fence = False
     steps: tuple = ()
     spans: tuple = ()
     completed: tuple = ()
@@ -796,8 +880,11 @@ class NullTelemetry:
     def histogram(self, name, bounds=None, help=""):
         return _NULL_METRIC
 
-    def annotate(self, name):
+    def annotate(self, name, **args):
         return contextlib.nullcontext()
+
+    def watch_compiles(self, owner):
+        pass
 
     def reset(self):
         pass

@@ -145,7 +145,7 @@ def test_telemetry_config_parse():
     assert TelemetryConfig.parse(False).level == "off"
     assert TelemetryConfig.parse(True).level == "metrics"
     assert TelemetryConfig.parse("trace").level == "trace"
-    cfg = TelemetryConfig(level="metrics", fence=True, step_ring=8)
+    cfg = TelemetryConfig(level="metrics", step_ring=8)
     assert TelemetryConfig.parse(cfg) is cfg
     with pytest.raises(ValueError, match="level"):
         TelemetryConfig(level="verbose")
@@ -466,3 +466,149 @@ def test_speculative_counters_through_registry(small_lm):
     assert h["count"] == c["serving_spec_rounds"]
     assert snap["histograms"]["serving_draft_round_s"]["count"] > 0
     assert c["serving_draft_time_s"] > 0 and c["serving_target_time_s"] > 0
+
+
+# ---------------------------------------------------------------------------
+# scheduler phase spans, device scopes, request stamps, compile counter
+# ---------------------------------------------------------------------------
+
+PHASES = ("sched.plan", "sched.dispatch", "sched.wait", "sched.sample",
+          "sched.commit")
+
+
+def test_annotate_carries_args_into_the_span_ring_and_export(tmp_path):
+    tel = Telemetry(TelemetryConfig(level="trace"))
+    with tel.annotate("sched.plan", step=3):
+        pass
+    [(name, _, dur, args)] = tel.spans
+    assert name == "sched.plan" and dur >= 0 and args == {"step": 3}
+    ev = json.loads(tel.export_chrome_trace(tmp_path / "t.json").read_text())
+    [span] = [e for e in ev["traceEvents"] if e.get("name") == "sched.plan"]
+    assert span["args"] == {"step": 3}
+    # below trace level the span is a bare profiler annotation
+    quiet = Telemetry(TelemetryConfig(level="metrics"))
+    with quiet.annotate("sched.plan", step=3):
+        pass
+    assert not quiet.spans
+
+
+def test_scheduler_phase_spans_nest_in_each_packed_step(small_lm):
+    cfg, model, params, qp = small_lm
+    eng = _mk_engine(model, qp, "trace")
+    eng.generate([[1, 2, 3, 4, 5], [6, 7]], max_new_tokens=3)
+    spans = list(eng.telemetry.spans)
+    steps = [s for s in spans if s[0] == "packed_step"]
+    assert [s[3]["step"] for s in steps] == list(range(eng.stats["packed_steps"]))
+    for name, t0, dur, args in steps:
+        mine = [s for s in spans if s[3].get("step") == args["step"]]
+        phases = [s for s in mine if s[0] in PHASES]
+        assert [s[0] for s in phases] == list(PHASES)  # once each, in order
+        for a, b in zip(phases, phases[1:]):
+            assert a[1] + a[2] <= b[1]
+        assert all(t0 <= s[1] and s[1] + s[2] <= t0 + dur for s in phases)
+        # admission runs before the packed step it feeds
+        [admit] = [s for s in mine if s[0] == "sched.admit"]
+        assert admit[1] + admit[2] <= t0
+
+
+def test_step_record_device_time_is_dispatch_plus_wait(small_lm):
+    cfg, model, params, qp = small_lm
+    eng = _mk_engine(model, qp, "metrics")
+    eng.generate([[1, 2, 3], [4, 5]], max_new_tokens=4)
+    tel = eng.telemetry
+    steps = list(tel.steps)
+    assert len(steps) == eng.stats["packed_steps"]
+    assert all(s["device_s"] > 0 and s["host_s"] >= 0 for s in steps)
+    # the target-time counter is the same dispatch + wait
+    assert tel.counter("serving_target_time_s").value == pytest.approx(
+        sum(s["device_s"] for s in steps))
+    assert not hasattr(TelemetryConfig(), "fence")
+
+
+def test_completed_request_stamps_at_metrics_level():
+    t, clock = _fake_clock()
+    tel = Telemetry(TelemetryConfig(level="metrics", max_requests=2), clock=clock)
+    for rid in range(3):
+        t[0] = 10.0 * rid
+        tel.request_submitted(rid, n_prompt=4)
+        t[0] += 1.0
+        tel.request_admitted(rid)
+        t[0] += 2.0
+        tel.request_event(rid, "prefill_chunk", start=0, n=2)
+        t[0] += 1.0
+        tel.request_event(rid, "prefill_chunk", start=2, n=2)
+        tel.first_token(rid)
+        t[0] += 1.0
+        tel.request_finished(rid, n_generated=1)
+    assert [tr.rid for tr in tel.completed] == [1, 2]  # bounded, newest kept
+    for tr in tel.completed:
+        base = 10.0 * tr.rid
+        assert (tr.t_enqueue, tr.t_admit, tr.t_first_chunk, tr.t_first_token,
+                tr.t_finish) == (base, base + 1, base + 3, base + 4, base + 5)
+        assert tr.events is None  # event lists stay a trace-level cost
+
+
+def test_engine_keeps_request_stamps_at_metrics_level(small_lm):
+    cfg, model, params, qp = small_lm
+    eng = _mk_engine(model, qp, "metrics")
+    eng.generate([[1, 2, 3, 4, 5, 6, 7], [8, 9]], max_new_tokens=3)
+    done = list(eng.telemetry.completed)
+    assert len(done) == 2
+    for tr in done:
+        assert tr.t_enqueue <= tr.t_admit <= tr.t_first_chunk <= tr.t_first_token \
+            <= tr.t_finish
+
+
+def test_compile_counter_lives_with_the_engine(small_lm):
+    import gc
+
+    from jax._src import monitoring
+
+    cfg, model, params, qp = small_lm
+    before = len(monitoring._event_duration_secs_listeners)
+    eng = _mk_engine(model, qp, "metrics", cache_len=48)  # shapes not yet compiled
+    assert len(monitoring._event_duration_secs_listeners) == before + 1
+    eng.generate([[1, 2, 3]], max_new_tokens=2)
+    snap = eng.snapshot()["counters"]
+    assert snap["serving_compiles"] > 0 and snap["serving_compile_s"] > 0
+    assert "serving_compiles " in eng.telemetry.expfmt()
+    del eng, snap
+    gc.collect()
+    assert len(monitoring._event_duration_secs_listeners) == before
+
+
+def test_scope_map_reads_op_name_metadata():
+    from repro.serving.telemetry import scope_map
+
+    text = """
+  %fusion.3 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop, calls=%c, metadata={op_type="mul" op_name="jit(packed_step)/layer_scan/while/body/kv_write/mul" source_file="x.py" source_line=3}
+  ROOT %custom-call.1 = f32[8]{0} custom-call(f32[8]{0} %fusion.3), metadata={op_name="jit(packed_step)/layer_scan/while/body/lut_gemm/jit(lut_gemm_fused)/pallas_call"}
+  %copy.2 = f32[8]{0} copy(f32[8]{0} %p), metadata={op_name="jit(packed_step)/dynamic_slice"}
+  %parameter.0 = f32[8]{0} parameter(0)
+"""
+    assert scope_map(text) == {"fusion.3": "layer_scan/kv_write",
+                               "custom-call.1": "layer_scan/lut_gemm",
+                               "copy.2": ""}
+
+
+def test_packed_step_scope_map_on_cpu(small_lm):
+    from repro.serving.telemetry import scope_map
+
+    """Every layer of the packed step carries its scope on the compiled
+    module's instructions (jnp routes on the CPU)."""
+    cfg, model, params, qp_plain = small_lm
+    spec = QuantSpec(base=QLinearConfig(detection="dynamic", outlier_frac=0.05),
+                     kv_bits=4)
+    qp = quantize_model(model, params, spec)
+    eng = ServingEngine(model, qp, ServeConfig.from_spec(
+        spec, cache_len=32, block_size=8, prefill_chunk=4), batch_slots=2)
+    sched = eng.scheduler
+    inner, calls = sched._packed_fn, []
+    sched._packed_fn = lambda *a: (calls.append(a), inner(*a))[1]
+    eng.generate([[1, 2, 3]], max_new_tokens=2)
+    text = inner.lower(*calls[0]).compile().as_text()
+    scopes = {p.split("/")[-1] for p in scope_map(text).values()}
+    assert {"layer_scan", "embed", "norm", "rope", "kv_write", "paged_attention",
+            "lut_gemm", "orizuru", "compensate", "mlp_act", "logits"} <= scopes
+    # a persistent-cache hit never brings back another revision's scopes
+    assert jax.config.jax_compilation_cache_include_metadata_in_key

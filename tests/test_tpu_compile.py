@@ -131,3 +131,41 @@ def test_paged_attn_compiles(one_chip, pool, window):
     _compile(step, one_chip, ((rows, 1, KV, G, HD), jnp.bfloat16),
              ((rows, max_blk), jnp.int32), ((rows,), jnp.int32),
              ((rows, 1), jnp.int32), *storage)
+
+
+def test_packed_step_carries_device_scopes(one_chip, monkeypatch):
+    """The whole packed step (two blocks at full width, every kernel route
+    on) compiles for the chip with the program's scopes on its
+    instructions: the names a profile's device time is read by."""
+    import dataclasses
+
+    from repro.core import QLinearConfig, QuantSpec, quantize_model
+    from repro.core import kernel_routing as kr
+    from repro.kernels import ops as kops
+    from repro.models import layers
+    from repro.models.model import build
+    from repro.serving.speculative import make_packed_fn
+    from repro.serving.telemetry import scope_map
+
+    monkeypatch.setattr(kr, "_AUTO_DEFAULT", True)
+    monkeypatch.setattr(kr, "_DETECT_AUTO_DEFAULT", True)
+    monkeypatch.setattr(layers, "_USE_PAGED_KERNEL", True)
+    monkeypatch.setattr(kops, "should_interpret", lambda: False)
+    model = build(dataclasses.replace(CONFIG, n_layers=2))
+    spec = QuantSpec(base=QLinearConfig(detection="dynamic", outlier_frac=FRAC),
+                     rules=[("mlp/wd", {"w_bits": 8})], kv_bits=4, kv_dtype="float32")
+    params = jax.eval_shape(lambda: quantize_model(
+        model, model.init(jax.random.PRNGKey(0)), spec))
+    max_blk, rows = 256 // BLOCK, 2 * SLOTS
+    pools = jax.eval_shape(lambda: model.init_caches(
+        SLOTS, 256, jnp.float32, quantized=True, layout="paged", block_size=BLOCK,
+        n_blocks=SLOTS * max_blk))
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    text = jax.jit(make_packed_fn(model)).lower(
+        on_chip(params), on_chip(pools), i32(SLOTS, max_blk), i32(rows), i32(rows, 1),
+        i32(rows), i32(rows, 1)).compile().as_text()
+    assert "tpu_custom_call" in text
+    scopes = {p.split("/")[-1] for p in scope_map(text).values()}
+    assert {"kv_write", "compensate", "orizuru", "lut_gemm", "paged_attention"} <= scopes
