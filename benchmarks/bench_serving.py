@@ -760,7 +760,8 @@ def run_outlier_phase(smoke: bool) -> None:
         "detect_kernel=jnp route leaked onto the Orizuru kernel"
     # the --smoke CI gates: detection live on the hot path, zero fallbacks
     assert st["outlier_detect_calls"] > 0 and st["outlier_fallbacks"] == 0, st
-    assert st["outlier_comp_gather"] + st["outlier_comp_scatter"] > 0, st
+    assert (st["outlier_comp_gather"] + st["outlier_comp_scatter"]
+            + st["outlier_comp_fused"]) > 0, st
     assert st["prefix_hit_tokens"] > 0, "prefix sharing was not exercised"
     assert st["accepted_tokens"] > 0, "speculation was not exercised"
     print(f"outlier_route,-,-,-,pallas={dts['pallas']:.2f}s "
@@ -769,6 +770,7 @@ def run_outlier_phase(smoke: bool) -> None:
           f"fallbacks={st['outlier_fallbacks']} "
           f"comp_gather={st['outlier_comp_gather']} "
           f"comp_scatter={st['outlier_comp_scatter']} "
+          f"comp_fused={st['outlier_comp_fused']} "
           f"token_identical=True (interpret={jax.default_backend() != 'tpu'})")
     emit("serving_outlier_ce_a3", 0.0,
          f"A3 dynamic {ce['a3_dynamic']:.4f} < static {ce['a3_static']:.4f} "
@@ -787,6 +789,7 @@ def run_outlier_phase(smoke: bool) -> None:
            outlier_fallbacks=st["outlier_fallbacks"],
            comp_gather=st["outlier_comp_gather"],
            comp_scatter=st["outlier_comp_scatter"],
+           comp_fused=st["outlier_comp_fused"],
            token_identical=True,
            a3_dynamic_beats_a3_static=True,
            config={"smoke": smoke, "outlier_frac": frac,

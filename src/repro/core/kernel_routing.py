@@ -31,8 +31,9 @@ actually ran?") a trace-time decision can answer truthfully; incrementing
 per execution would need a host callback on the serving hot path. The
 serving scheduler surfaces these counts as lazy gauges in the PR-6
 telemetry registry (``serving_lut_*`` and ``serving_outlier_*``) and in
-``ServingEngine.stats``. Compensation-route choices (gather vs scatter,
-``QLinearConfig.comp_mode`` resolution) are counted here too.
+``ServingEngine.stats``. Compensation-route choices (gather, scatter, or
+fused into the LUT-GEMM kernel; ``QLinearConfig.comp_mode`` resolution)
+are counted here too.
 
 Fallbacks are never silent: an unsupported tier demoted from a requested
 ``pallas`` route increments a counter AND warns once per reason
@@ -82,7 +83,7 @@ _WARNED: set[str] = set()
 
 # Outlier-detection routing state, mirroring the GEMM counters above:
 # (tier, route) dispatches, explicit fallbacks, and the comp-route choice
-# (gather vs scatter) that the dual branch resolves per trace.
+# (gather, scatter or fused) that the dual branch resolves per trace.
 _DETECT_DISPATCH: Counter = Counter()
 _DETECT_FALLBACKS: Counter = Counter()
 _COMP_ROUTES: Counter = Counter()
@@ -187,7 +188,8 @@ def record_detect_fallback(tier: str, reason: str) -> None:
 
 
 def record_comp_route(mode: str) -> None:
-    """Count the resolved compensation route ("gather" or "scatter")."""
+    """Count the resolved compensation route ("gather", "scatter", or
+    "fused": folded into the fused LUT-GEMM kernel)."""
     _COMP_ROUTES[mode] += 1
 
 
@@ -237,7 +239,8 @@ def detect_fallback_count() -> int:
 
 
 def comp_route_counts() -> dict[str, int]:
-    """``{"gather": n, "scatter": m}`` resolved compensation routes."""
+    """``{"fused": f, "gather": n, "scatter": m}`` resolved compensation
+    routes (routes never taken are absent)."""
     return dict(sorted(_COMP_ROUTES.items()))
 
 

@@ -12,6 +12,13 @@ Fig. 4(a)), the **look-ahead** scheme (Fig. 4(b)) runs two branches:
 Y* + Y' is mathematically identical to detect-then-split — asserted bit-level
 (fp32) in tests.
 
+On the fused LUT-GEMM kernel route the two forms meet again: detection runs
+first and :func:`outlier_mask` hands the kernel each token's outlier
+channels, where the tile uses the exact activation instead of its centroid —
+detect-then-split inside the one dot, so no weight row is gathered or
+unpacked. :func:`compensate_gather` / :func:`compensate_scatter` serve the
+jnp route (and the kernel route's explicit ``comp_mode`` ablation).
+
 TPU adaptation of Orizuru: the ASIC pops one (value, index) per cycle from a
 two-fold tournament tree. On TPU we return the whole top-k/bottom-k at once
 (``jax.lax.top_k`` here; the Pallas kernel in ``kernels/topk_outlier.py``
@@ -45,6 +52,7 @@ __all__ = [
     "static_thresholds",
     "outlier_residuals",
     "outlier_residuals_direct",
+    "outlier_mask",
     "compensate_gather",
     "compensate_scatter",
     "orizuru_comparisons",
@@ -156,6 +164,19 @@ def outlier_residuals_direct(
         idx = cb.assign_via_boundaries((v / scale).astype(jnp.float32), codebook)
     deq = lookup(codebook, idx) * scale
     return (v - deq) * out.mask
+
+
+def outlier_mask(out: OutlierSet, k_channels: int) -> jax.Array:
+    """Dense (..., K) int32 mask: 1 at each token's outlier channels.
+
+    The fused LUT-GEMM kernel's outlier operand (detect-then-split in the
+    tile). Masked slots mark nothing, and a channel listed twice (top-k and
+    bottom-k of a token with fewer than 2k distinct values) is marked once.
+    A one-hot compare-reduce over the 2k slots.
+    """
+    ch = jnp.where(out.mask > 0, out.channels, -1)
+    hit = ch[..., None] == jnp.arange(k_channels, dtype=jnp.int32)
+    return jnp.any(hit, axis=-2).astype(jnp.int32)
 
 
 def compensate_gather(

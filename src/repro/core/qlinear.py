@@ -20,11 +20,16 @@ detection runs the Pallas Orizuru tournament kernel or ``lax.top_k``. On the
 jnp GEMM route with Pallas detection the layer uses the STREAMING form —
 bucketize + dual top-k in one pass over the activation tile
 (``kernels/ops.quantize_outlier_streaming``) — so detection adds no extra
-HBM roundtrip; on the fused GEMM route the detection-only kernel composes
-via ``outlier_residuals_direct`` (q(x) recomputed at the 2k gathered
-channels, indices never materialized). All four combinations are bit-
-identical in their index/value selection, so greedy serving tokens match
-across routes. The A3 activation tier (8-entry codebook) is legal only with
+HBM roundtrip; the jnp route then compensates separately, by weight-row
+gather (few tokens) or scatter + dense GEMM (``comp_auto_tokens``). On the
+fused GEMM route detection (dynamic or static) runs first and compensation
+is FOLDED into the kernel tile: the token's outlier channels enter the dot
+with their exact ``x/s`` instead of their centroid (detect-then-split, equal
+to the two branches' sum), so no weight row is gathered, scattered or
+unpacked outside the kernel (``comp:fused``; an explicit ``comp_mode`` keeps
+the separate branch as an ablation). Detection routes are bit-identical in
+their index/value selection, so greedy serving tokens match across them.
+The A3 activation tier (8-entry codebook) is legal only with
 ``detection != "none"`` (see ``QLinearConfig.validate``).
 """
 
@@ -68,7 +73,9 @@ class QLinearConfig:
     outlier_frac: float = 0.005  # per side; paper default 0.5% + 0.5%
     detection: Detection = "dynamic"  # OASIS='dynamic', OASIS-S='static'
     comp_mode: CompMode = "auto"
-    comp_auto_tokens: int = 64  # comp_mode="auto": gather at <= this many tokens
+    # comp_mode="auto": folded into the fused kernel on the pallas route;
+    # on the jnp route, gather at <= this many tokens, else scatter
+    comp_auto_tokens: int = 64
     scale_mode: qz.ScaleMode = "rms"
     compute_dtype: object = jnp.float32
     use_kernel: bool = False  # legacy boolean opt-in; kernel="pallas" spelling
@@ -205,6 +212,24 @@ def _tokens(x: jax.Array) -> int:
     return math.prod(x.shape[:-1]) if x.ndim > 1 else 1
 
 
+def _detect(p: QLinearParams, x: jax.Array, cfg: QLinearConfig,
+            detect_route: str, k_out: int) -> ol.OutlierSet:
+    """Top-k + bottom-k (dynamic) or threshold (static) outliers of ``x``."""
+    with jax.named_scope("orizuru"):
+        xf = x.astype(jnp.float32)
+        if cfg.detection == "dynamic" and detect_route == "pallas":
+            from repro.kernels import ops as kops
+
+            # detection-only Orizuru kernel: the fused-GEMM main branch has
+            # no activation indices to stream; a_bits > 4 on the jnp route
+            # lands here too (the streaming form's compare chain, like fused
+            # bucketize, tops out at A4)
+            return kops.topk_outlier(xf, k_out)
+        if cfg.detection == "dynamic":
+            return ol.detect_outliers_topk(xf, k_out)
+        return ol.detect_outliers_static(xf, p.thr_lo, p.thr_hi, k_out)
+
+
 def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = None) -> jax.Array:
     """Dual-branch forward (paper Fig. 7). Output dtype follows ``x``.
 
@@ -252,20 +277,29 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
             else:
                 kr.record_detect_dispatch(tier, "jnp")
 
+    # kernel route with top-k / threshold detection: the outlier branch is
+    # folded into the fused LUT-GEMM tile (detect-then-split), so detection
+    # runs first; an explicit comp_mode keeps the separate branch (ablation)
+    fold = (route == "pallas" and k_out > 0 and cfg.comp_mode == "auto"
+            and cfg.detection in ("dynamic", "static"))
+    qa = None
+    outs = _detect(p, x, cfg, detect_route, k_out) if fold else None
+
     # ---- main branch: look-ahead LUT-GEMM over ALL activations ------------
     # (device scopes lut_gemm / orizuru / compensate: telemetry.SCOPES)
-    qa = None
-    outs = None
     if route == "pallas":
         from repro.kernels import ops as kops
 
         # ONE fused Pallas dispatch: bucketize x in VMEM + index-GEMM.
         # Handles every weight tier (W<=4 nibble-packed, W5-8 byte-packed);
         # no QuantizedActivation and no dequantized (K, N) weight exist.
+        # With ``outliers`` the kernel also carries the outlier branch (its
+        # mask is built under the compensate scope).
         with jax.named_scope("lut_gemm"):
             y = kops.lut_gemm_fused(x, p.act_codebook, p.qw,
                                     scale_mode=cfg.scale_mode,
-                                    out_dtype=cfg.compute_dtype)
+                                    out_dtype=cfg.compute_dtype,
+                                    outliers=outs)
     else:
         if (detect_route == "pallas" and cfg.detection == "dynamic"
                 and a_nbits <= 4):
@@ -285,7 +319,9 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
                               compute_dtype=cfg.compute_dtype)
 
     # ---- outlier branch: detect, residual, compensate ----------------------
-    if cfg.detection == "static_dense" and cfg.outlier_frac > 0:
+    if fold:
+        kr.record_comp_route("fused")
+    elif cfg.detection == "static_dense" and cfg.outlier_frac > 0:
         # OASIS-S with dense masked compensation: zero sorts, one extra dense
         # matmul. Orizuru/lax.top_k at 32k-token prefill means a full sort per
         # projection (~12 GB/device of sort+gather workspace x concurrency —
@@ -305,24 +341,9 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
             r = jnp.where(mask, xf - deq, 0)
             w = qz.dequantize_weight(p.qw, cfg.compute_dtype)
             y = y + jnp.einsum("...k,kn->...n", r, w)
-    elif cfg.detection != "none" and cfg.outlier_frac > 0:
+    elif k_out > 0:
         if outs is None:
-            with jax.named_scope("orizuru"):
-                if cfg.detection == "dynamic" and detect_route == "pallas":
-                    from repro.kernels import ops as kops
-
-                    # detection-only Orizuru kernel: the fused-GEMM main
-                    # branch (qa is None) composes via outlier_residuals_direct
-                    # below; a_bits > 4 on the jnp route lands here too (the
-                    # streaming form's compare chain, like fused bucketize,
-                    # tops out at A4)
-                    outs = kops.topk_outlier(x.astype(jnp.float32), k_out)
-                elif cfg.detection == "dynamic":
-                    outs = ol.detect_outliers_topk(x.astype(jnp.float32), k_out)
-                else:
-                    outs = ol.detect_outliers_static(
-                        x.astype(jnp.float32), p.thr_lo, p.thr_hi, k_out
-                    )
+            outs = _detect(p, x, cfg, detect_route, k_out)
         mode = cfg.comp_mode
         if mode == "auto":
             # decode-ish (few tokens): row-gather; prefill-ish: scatter+dense GEMM
@@ -330,10 +351,10 @@ def qlinear_apply(p: QLinearParams, x: jax.Array, cfg: QLinearConfig | None = No
         kr.record_comp_route(mode)
         with jax.named_scope("compensate"):
             if qa is None:
-                # kernel route: q(x) at the 2k outlier channels, recomputed
-                # from the gathered values (quantization is elementwise) —
-                # bit-equal to the qa-based residual, without materializing
-                # indices
+                # kernel route (explicit comp_mode): q(x) at the 2k outlier
+                # channels, recomputed from the gathered values (quantization
+                # is elementwise) — bit-equal to the qa-based residual,
+                # without materializing indices
                 r = ol.outlier_residuals_direct(
                     outs, qz.token_scale(x, cfg.scale_mode), p.act_codebook,
                     mul_form=mul_form)

@@ -15,7 +15,10 @@ sharing one tiling scheme:
   codebook's decision boundaries *inside the tile* (the Clustering-Unit
   sum-of-compares, same formulation as ``kernels/bucketize.py``), and
   immediately runs the index-GEMM. Activation indices exist only in VMEM —
-  the separate quantize pass and its idx HBM roundtrip are gone.
+  the separate quantize pass and its idx HBM roundtrip are gone. Given a
+  per-token outlier mask it also folds in the outlier branch: marked
+  channels enter the dot with their exact ``x/s`` instead of the centroid,
+  so no weight row is gathered or unpacked outside the kernel.
 
 Nibble planes. A packed byte holds output channels ``2i`` (low nibble) and
 ``2i+1`` (high nibble) — the format ``core.quantize.pack_int4`` owns. Rather
@@ -116,16 +119,24 @@ def _index_kernel(a_idx_ref, w_ref, a_book_ref, w_book_ref, *o_refs,
     _accumulate(a, w_ref, w_book_ref, o_refs, byte_packed=byte_packed)
 
 
-def _fused_kernel(x_ref, s_ref, w_ref, bounds_ref, a_book_ref, w_book_ref,
-                  *o_refs, byte_packed: bool,
-                  mul_form: bool, block_k: int, k_true: int, masked_k: bool):
+def _fused_kernel(*refs, byte_packed: bool, mul_form: bool, fold: bool,
+                  block_k: int, k_true: int, masked_k: bool):
     """Bucketize-then-GEMM in one pass: activation indices never leave VMEM.
+
+    refs: x, s, [outlier mask when ``fold``], w, bounds, a_book, w_book,
+    then the output planes.
 
     ``mul_form`` selects the compare formulation so indices are bit-identical
     to ``core.quantize.quantize_activation`` for the matching input dtype:
     f32 compares ``x/s >= b_i`` (the searchsorted path), bf16 compares
     ``x >= s*b_i`` (the fused sum-of-compares path).
+
+    ``fold`` is the outlier branch in the tile (detect-then-split): where the
+    (bm, bk) outlier mask is set, the activation is the exact ``x/s`` instead
+    of its centroid, so the one dot is main branch plus compensation.
     """
+    x_ref, s_ref = refs[:2]
+    w_ref, bounds_ref, a_book_ref, w_book_ref, *o_refs = refs[2 + fold:]
     x = x_ref[...].astype(jnp.float32)  # (bm, bk)
     s = s_ref[...].astype(jnp.float32)  # (bm, 1) per-token scale
     idx = jnp.zeros(x.shape, jnp.int32)
@@ -138,6 +149,8 @@ def _fused_kernel(x_ref, s_ref, w_ref, bounds_ref, a_book_ref, w_book_ref,
             idx += (xn >= bounds_ref[i]).astype(jnp.int32)
 
     a = lookup(a_book_ref, idx)
+    if fold:
+        a = jnp.where(refs[2][...] != 0, x / s if mul_form else xn, a)
     if masked_k:
         a = _mask_padded_k(a, block_k, k_true)
     _accumulate(a, w_ref, w_book_ref, o_refs, byte_packed=byte_packed)
@@ -239,6 +252,7 @@ def fused_lut_gemm_kernel_call(
     *,
     byte_packed: bool = False,
     mul_form: bool = False,
+    outlier_mask: jax.Array | None = None,  # (M, K) 32-bit, 1 at outliers
     block_m: int | None = None,
     block_n: int | None = None,
     block_k: int | None = None,
@@ -251,6 +265,11 @@ def fused_lut_gemm_kernel_call(
     centroid lookup — happens inside the tile. Padded rows must carry a
     nonzero ``scale`` (this wrapper pads with ones) so the in-kernel
     division stays NaN-free; padded rows are sliced off regardless.
+
+    ``outlier_mask`` folds the outlier branch into the tile: at its nonzero
+    entries the activation is the exact ``x/s``, elsewhere the centroid, so
+    the result is ``sum_k a[m,k] * wBook[wIdx[k,n]]`` with both branches in
+    ``a``. It is tiled like ``x``; a 32-bit type keeps the (8, 128) tiling.
     """
     m, k = x.shape
     n = w_packed.shape[1] * (1 if byte_packed else 2)
@@ -258,10 +277,13 @@ def fused_lut_gemm_kernel_call(
     pm, pk = geo[3], geo[5]
     s = jnp.pad(scale.astype(jnp.float32), ((0, pm), (0, 0)),
                 constant_values=1.0)
+    rows = [jnp.pad(x, ((0, pm), (0, pk))), s]
+    if outlier_mask is not None:
+        rows.append(jnp.pad(outlier_mask, ((0, pm), (0, pk))))
     return _index_gemm_call(
-        _fused_kernel, geo, [jnp.pad(x, ((0, pm), (0, pk))), s], w_packed,
+        _fused_kernel, geo, rows, w_packed,
         [bounds.astype(jnp.float32), a_book.astype(jnp.float32),
          w_book.astype(jnp.float32)],
         m=m, n=n, k=k, byte_packed=byte_packed, interpret=interpret,
-        mul_form=mul_form,
+        mul_form=mul_form, fold=outlier_mask is not None,
     )

@@ -4,10 +4,10 @@ These adapt the kernels to the core library's types (QuantizedActivation /
 QuantizedWeight / OutlierSet), handle arbitrary leading batch dims, apply the
 rank-1 scales, and auto-select interpret mode off-TPU. On a TPU they run
 compiled (``interpret=False``): ``tests/test_tpu_compile.py`` compiles the
-fused LUT-GEMM (W4 nibble and W8 byte tiers, f32 and bf16 inputs), both
-Orizuru kernels and both paged-attention pools for a described v5e at
-h2o-danube-1.8B widths, and ``chip_smoke.py`` serves that model through
-all of them on a v5e chip.
+fused LUT-GEMM (W4 nibble and W8 byte tiers, f32 and bf16 inputs, with and
+without the outlier operand), both Orizuru kernels and both paged-attention
+pools for a described v5e at h2o-danube-1.8B widths, and ``chip_smoke.py``
+serves that model through all of them on a v5e chip.
 
 ``lut_gemm`` dispatches both weight tiers (nibble-packed <= 4 bits, byte-
 packed 5..8 bits); ``lut_gemm_fused`` is the serving hot path: raw
@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.codebook import boundaries_from_centroids
-from repro.core.outlier import OutlierSet
+from repro.core.outlier import OutlierSet, outlier_mask
 from repro.core.quantize import QuantizedActivation, QuantizedWeight, token_scale
 from repro.kernels.bucketize import bucketize_kernel_call
 from repro.kernels.lut_gemm import fused_lut_gemm_kernel_call, lut_gemm_kernel_call
@@ -72,12 +72,14 @@ _CANDIDATES = (
 
 
 def _block_key(m: int, k: int, n: int, w_nbits: int, a_nbits: int,
-               fused: bool) -> tuple:
-    return (m, k, n, w_nbits, a_nbits, fused)
+               fused: bool, folded: bool = False) -> tuple:
+    # ``folded``: the fused call with its outlier operand, whose tiles hold
+    # one more (bm, bk) block than the sweep timed
+    return (m, k, n, w_nbits, a_nbits, fused, folded)
 
 
-def _cached_blocks(m, k, n, w_nbits, a_nbits, fused) -> dict:
-    hit = _BLOCK_CACHE.get(_block_key(m, k, n, w_nbits, a_nbits, fused))
+def _cached_blocks(m, k, n, w_nbits, a_nbits, fused, folded=False) -> dict:
+    hit = _BLOCK_CACHE.get(_block_key(m, k, n, w_nbits, a_nbits, fused, folded))
     if hit is None:
         return {}
     bm, bn, bk = hit
@@ -159,7 +161,8 @@ def lut_gemm(qa: QuantizedActivation, qw: QuantizedWeight,
 @partial(jax.jit, static_argnames=("scale_mode", "out_dtype", "blocks"))
 def lut_gemm_fused(x: jax.Array, codebook: jax.Array, qw: QuantizedWeight,
                    scale_mode: str = "rms", out_dtype=jnp.float32,
-                   blocks: tuple[int, int, int] | None = None) -> jax.Array:
+                   blocks: tuple[int, int, int] | None = None,
+                   outliers: OutlierSet | None = None) -> jax.Array:
     """Fused quantize+index-GEMM: raw activations in, scaled output out.
 
     The per-token scale (a rank-1 full-K reduction XLA fuses) is computed
@@ -168,12 +171,22 @@ def lut_gemm_fused(x: jax.Array, codebook: jax.Array, qw: QuantizedWeight,
     input dtype (f32: searchsorted form; bf16: sum-of-compares mul form),
     so routing through this path preserves greedy token identity with the
     jnp factorized route.
+
+    ``outliers`` (detected on ``x``, leading dims alike) folds the outlier
+    branch into the kernel: their channels enter the dot with the exact
+    ``x/s``, so the output is the main branch plus compensation
+    (detect-then-split) and no weight row leaves the kernel.
     """
     x2d, lead = _flatten_leading(x)
     m, k = x2d.shape
     a_nbits = int(codebook.shape[0]).bit_length() - 1
     kw = (dict(zip(("block_m", "block_n", "block_k"), blocks)) if blocks
-          else _cached_blocks(m, k, qw.shape[1], qw.nbits, a_nbits, True))
+          else _cached_blocks(m, k, qw.shape[1], qw.nbits, a_nbits, True,
+                              outliers is not None))
+    omask = None
+    if outliers is not None:
+        with jax.named_scope("compensate"):
+            omask = outlier_mask(outliers, k).reshape(m, k)
     s = token_scale(x2d, scale_mode)  # (M, 1) f32
     book = codebook.astype(jnp.float32)
     y = fused_lut_gemm_kernel_call(
@@ -182,6 +195,7 @@ def lut_gemm_fused(x: jax.Array, codebook: jax.Array, qw: QuantizedWeight,
         qw.codebook.astype(jnp.float32),
         byte_packed=qw.nbits > 4,
         mul_form=x.dtype == jnp.bfloat16,
+        outlier_mask=omask,
         interpret=should_interpret(),
         **kw,
     )

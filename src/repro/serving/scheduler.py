@@ -329,7 +329,8 @@ class Scheduler:
         }
         # Orizuru outlier-engine dispatch: which detection path each dual-
         # branch projection compiled into, plus the compensation route
-        # (gather vs scatter) its comp_mode resolved to. Same lazy-gauge
+        # (gather, scatter, or fused into the LUT-GEMM kernel) its comp_mode
+        # resolved to. Same lazy-gauge
         # pattern as the LUT-GEMM counters above.
         self._g_outlier = {
             "outlier_detect_calls": tel.gauge(
@@ -352,6 +353,10 @@ class Scheduler:
                 "serving_outlier_comp_scatter",
                 fn=lambda: _kr.comp_route_counts().get("scatter", 0),
                 help="compensations resolved to the scatter+dense route"),
+            "outlier_comp_fused": tel.gauge(
+                "serving_outlier_comp_fused",
+                fn=lambda: _kr.comp_route_counts().get("fused", 0),
+                help="compensations folded into the fused LUT-GEMM kernel"),
         }
         self._h_accept = tel.histogram(
             "serving_spec_accepted_per_round",

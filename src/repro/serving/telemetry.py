@@ -827,7 +827,7 @@ SCOPES = (
     "paged_attention",  # attention through the block tables
     "lut_gemm",  # the projections' main branch: activation quantize + index GEMM
     "orizuru",  # outlier detection
-    "compensate",  # outlier residuals, weight-row gather / scatter, index unpack
+    "compensate",  # fused route: the outlier mask; else residuals, row gather / scatter
     "mlp_act",  # the MLP's gated activation
     "logits",  # final norm and the vocabulary projection
 )

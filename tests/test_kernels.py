@@ -29,11 +29,20 @@ except ImportError:  # pragma: no cover - env-dependent
             return None
 
 from repro.core.codebook import boundaries_from_centroids
-from repro.core.outlier import detect_outliers_topk
+from repro.core.outlier import (
+    OutlierSet,
+    compensate_gather,
+    detect_outliers_static,
+    detect_outliers_topk,
+    outlier_mask,
+    outlier_residuals_direct,
+)
 from repro.core.quantize import (
+    dequantize_weight,
     fit_activation_codebook,
     quantize_activation,
     quantize_weight,
+    token_scale,
 )
 from repro.kernels import ops, ref
 from repro.kernels.bucketize import bucketize_kernel_call
@@ -218,6 +227,80 @@ def test_fused_ops_bit_identical_indices(dtype):
     qa = quantize_activation(x, book)
     y_two = lut_jnp(qa, qw, out_dtype=jnp.float32, compute_dtype=jnp.float32)
     np.testing.assert_allclose(y_fused, y_two, rtol=2e-5, atol=1e-4)
+
+
+def _outlier_case(x, k_out, static):
+    """Outliers of ``x``: Orizuru's top-k + bottom-k, or a static-style set
+    whose thresholds leave some slots masked."""
+    xf = x.astype(jnp.float32)
+    if not static:
+        return detect_outliers_topk(xf, k_out)
+    hi = jnp.quantile(xf, 0.99)
+    return detect_outliers_static(xf, -hi, hi, k_out)
+
+
+def _marked(outs, k):
+    """The oracle's own outlier mask, set slot by slot in numpy."""
+    ch, keep = np.asarray(outs.channels), np.asarray(outs.mask) > 0
+    marked = np.zeros((ch.shape[0], k), bool)
+    for row in range(ch.shape[0]):
+        marked[row, ch[row][keep[row]]] = True
+    return marked
+
+
+@pytest.mark.parametrize("static", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m,k,n,w_nbits,bm,bk", [
+    (24, 300, 130, 4, 16, 128),  # nibble tier, M % bm and K % bk ragged
+    (13, 190, 66, 8, 16, 128),   # byte tier, M % bm and K % bk ragged
+])
+def test_fused_kernel_outlier_fold_matches_oracle(m, k, n, w_nbits, bm, bk,
+                                                  dtype, static):
+    """Outlier operand in the tile == detect-then-split in plain jnp:
+    centroids everywhere, the exact x/s at the marked channels, then the
+    scaled GEMM."""
+    from repro.kernels.lut_gemm import fused_lut_gemm_kernel_call
+
+    x, qw, book = _fused_case(m + k, m, k, n, w_nbits, dtype)
+    outs = _outlier_case(x, 4, static)
+    if static:
+        keep = np.asarray(outs.mask)
+        assert 0 < keep.sum() < keep.size  # some slots really are masked
+    qa = quantize_activation(x, book)
+    y = fused_lut_gemm_kernel_call(
+        x, qa.scale, qw.packed, boundaries_from_centroids(book), book,
+        qw.codebook, byte_packed=w_nbits > 4, mul_form=dtype == jnp.bfloat16,
+        outlier_mask=outlier_mask(outs, k), block_m=bm, block_k=bk)
+    xf = x.astype(jnp.float32)
+    a = jnp.where(_marked(outs, k), xf / qa.scale,
+                  book[qa.idx.astype(jnp.int32)])
+    want = (a * qa.scale) @ dequantize_weight(qw)
+    np.testing.assert_allclose(y * qa.scale * qw.scale, want,
+                               rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("w_nbits", [4, 8])
+def test_fused_fold_matches_gather_compensation(w_nbits):
+    """The folded kernel == today's separate branch (fused main GEMM plus
+    the row-gather compensation), on inputs with distinct values."""
+    x, qw, book = _fused_case(11, 21, 260, 96, w_nbits, jnp.float32)
+    assert all(np.unique(row).size == row.size for row in np.asarray(x))
+    outs = detect_outliers_topk(x, 3)
+    r = outlier_residuals_direct(outs, token_scale(x), book)
+    y_split = ops.lut_gemm_fused(x, book, qw) + compensate_gather(r, outs, qw)
+    np.testing.assert_allclose(ops.lut_gemm_fused(x, book, qw, outliers=outs),
+                               y_split, rtol=2e-5, atol=1e-4)
+
+
+def test_outlier_mask_marks_each_channel_once():
+    """Masked slots mark nothing; a channel in both the top-k and the
+    bottom-k (a token with fewer than 2k distinct values) is marked once."""
+    outs = OutlierSet(values=jnp.zeros((2, 4)),
+                      channels=jnp.array([[1, 3, 3, 0], [2, 5, 0, 4]], jnp.int32),
+                      mask=jnp.array([[1, 1, 1, 1], [1, 0, 1, 0]], jnp.float32))
+    got = outlier_mask(outs, 6)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(got, [[1, 1, 0, 1, 0, 0], [1, 0, 1, 0, 0, 0]])
 
 
 def test_fused_leading_batch_dims():

@@ -304,6 +304,54 @@ def test_detect_dispatch_counters_record_routes():
     assert snap["_detect_kernel_calls"] == 1 and snap["_detect_fallbacks"] == 0
 
 
+@pytest.mark.parametrize("detection", ["dynamic", "static"])
+def test_kernel_route_folds_compensation(detection):
+    """On the kernel route the outlier branch is folded into the fused
+    LUT-GEMM (comp:fused, no gather or scatter); an explicit comp_mode keeps
+    the separate branch as an ablation, and agrees with the fold."""
+    p = _layer(QLinearConfig(detection=detection, outlier_frac=0.02,
+                             kernel="pallas"), seed=12)
+    x = jax.random.normal(jax.random.PRNGKey(5), (6, 128)) * 2
+    kr.reset()
+    y = qlinear_apply(p, x)
+    assert kr.comp_route_counts() == {"fused": 1}
+    for mode in ("gather", "scatter"):
+        kr.reset()
+        y_split = qlinear_apply(p, x, cfg=dataclasses.replace(p.cfg, comp_mode=mode))
+        assert kr.comp_route_counts() == {mode: 1}
+        np.testing.assert_allclose(y, y_split, rtol=2e-5, atol=1e-4)
+
+
+def test_jnp_route_keeps_gather_and_scatter():
+    p = _layer(QLinearConfig(detection="dynamic", outlier_frac=0.02,
+                             kernel="jnp", comp_auto_tokens=4), seed=13)
+    kr.reset()
+    qlinear_apply(p, jax.random.normal(jax.random.PRNGKey(6), (4, 128)))
+    qlinear_apply(p, jax.random.normal(jax.random.PRNGKey(7), (5, 128)))
+    assert kr.comp_route_counts() == {"gather": 1, "scatter": 1}
+
+
+def test_engine_reports_fused_compensation():
+    """Serving on the kernel route: the engine's outlier_comp_fused stat
+    counts the fold, beside the gather and scatter stats."""
+    from repro.configs.base import get_smoke_config
+    from repro.models.model import build, quantize_model
+    from repro.serving.engine import ServeConfig, ServingEngine
+
+    model = build(get_smoke_config("llama3_2_1b"))
+    spec = QuantSpec(base=QLinearConfig(detection="dynamic", outlier_frac=0.02,
+                                        kernel="pallas"))
+    qp = quantize_model(model, model.init(jax.random.PRNGKey(0)), spec)
+    eng = ServingEngine(model, qp, ServeConfig(cache_len=32, cache_dtype="float32",
+                                               block_size=8),
+                        batch_slots=2)
+    kr.reset()
+    eng.generate([[1, 2, 3], [4]], max_new_tokens=2)
+    stats = eng.stats
+    assert stats["outlier_comp_fused"] > 0
+    assert stats["outlier_comp_gather"] == stats["outlier_comp_scatter"] == 0
+
+
 def test_static_pallas_detect_fallback_is_explicit():
     """Static (OASIS-S) detection has no tournament: a requested pallas
     detect route is demoted — warned once, counted, bit-equal to jnp."""

@@ -5,9 +5,10 @@ described ``v5e:2x2`` topology, and refuses what the chip would refuse
 (blocks off the (8, 128) tiling, VMEM over the scoped limit, ops Mosaic does
 not lower). Interpret-mode parity tests cannot see any of that. Shapes are
 those of one packed serving step: d_model 2560, d_ff 6912, 8 KV heads of
-head_dim 80 (GQA 32/8), W4A4 projections plus the W8 ``mlp/wd`` tier, int4
-and bf16 paged KV pools at cache_len 2048, and M = 8 (decode slots) or 40
-(slots + prefill chunk) token rows.
+head_dim 80 (GQA 32/8), W4A4 projections plus the W8 ``mlp/wd`` tier
+(with and without the outlier operand that folds compensation into the
+LUT-GEMM tile), int4 and bf16 paged KV pools at cache_len 2048, and M = 8
+(decode slots) or 40 (slots + prefill chunk) token rows.
 
 The topology is described inside a module fixture, never while a module is
 imported: only one process at a time may load the TPU library.
@@ -71,21 +72,25 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
+@pytest.mark.parametrize("fold", [False, True],
+                         ids=["main", "with_outlier_operand"])
 @pytest.mark.parametrize("m", [SLOTS, SLOTS + CHUNK])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("name,k,n,w_bits", PROJECTIONS)
-def test_fused_lut_gemm_compiles(one_chip, name, k, n, w_bits, dtype, m):
+def test_fused_lut_gemm_compiles(one_chip, name, k, n, w_bits, dtype, m, fold):
     byte_packed = w_bits > 4
     packed_n = n if byte_packed else n // 2
 
-    def step(x, scale, w, bounds, a_book, w_book):
+    def step(x, scale, w, bounds, a_book, w_book, *outlier_mask):
         return fused_lut_gemm_kernel_call(
             x, scale, w, bounds, a_book, w_book, byte_packed=byte_packed,
-            mul_form=dtype == jnp.bfloat16, interpret=False)
+            mul_form=dtype == jnp.bfloat16,
+            outlier_mask=outlier_mask[0] if fold else None, interpret=False)
 
     _compile(step, one_chip, ((m, k), dtype), ((m, 1), jnp.float32),
              ((k, packed_n), jnp.uint8), ((15,), jnp.float32),
-             ((16,), jnp.float32), ((2**w_bits,), jnp.float32))
+             ((16,), jnp.float32), ((2**w_bits,), jnp.float32),
+             *([((m, k), jnp.int32)] if fold else []))
 
 
 @pytest.mark.parametrize("m", [SLOTS, SLOTS + CHUNK])
@@ -169,3 +174,8 @@ def test_packed_step_carries_device_scopes(one_chip, monkeypatch):
     assert "tpu_custom_call" in text
     scopes = {p.split("/")[-1] for p in scope_map(text).values()}
     assert {"kv_write", "compensate", "orizuru", "lut_gemm", "paged_attention"} <= scopes
+    # outlier compensation is folded into the LUT-GEMM kernel: no weight's
+    # index matrix is unpacked to int32 outside it
+    for _, k, n, _ in PROJECTIONS:
+        for shape in (f"s32[{k},{n}]", f"s32[{k},{n // 2},2]"):
+            assert shape not in text, shape
