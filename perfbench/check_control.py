@@ -34,17 +34,15 @@ def control_picks(cfg: dict, seed: int, seqs: list, starts: list, serve: dict) -
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import quantize_model
     from repro.models.model import build
     from repro.serving.speculative import make_packed_fn
 
-    from perfbench import discover, weights
+    from perfbench import discover
+    from perfbench.harness import build_params
 
     model = build(discover.model_config(cfg))
     spec = discover.quant_spec(cfg["control"])
-    params = weights.make_weights(jax.eval_shape(model.init, jax.random.PRNGKey(0)), cfg, seed)
-    qp = jax.block_until_ready(quantize_model(model, params, spec))
-    del params
+    qp = build_params(model, cfg, spec, seed)
     bs = serve["block_size"]
     nb = -(-serve["cache_len"] // bs)
     rows = serve["token_budget"]

@@ -1,8 +1,8 @@
-"""One run of one cell: build the configuration's weights from the seed,
-quantize them through the serving program's public path, serve the cell's
-traffic through ``ServingEngine``'s paged scheduler for the measured
-window, check the served tokens against the plain reference, and return
-the result line.
+"""One run of one cell: build the configuration's weights from the seed a
+block at a time, quantize each through the serving program's public path,
+serve the cell's traffic through ``ServingEngine``'s paged scheduler for
+the measured window, check the served tokens against the plain reference
+(run a layer at a time), and return the result line.
 
 Set-up (``setup_s``) runs from the start of the process to the opening of
 the window: weights, quantization, engine, compilation (from JAX's
@@ -161,18 +161,46 @@ def percentile(values, q: float) -> float:
     return float(v[lo] + (v[hi] - v[lo]) * (pos - lo))
 
 
+def build_params(model, cfg: dict, spec, seed: int) -> dict:
+    """The served parameter tree: ``cfg``'s weights from ``seed`` quantized
+    under ``spec`` through the program's ``quantize_params``, one block at a
+    time. Each block is made in bf16, quantized and written into its slot
+    of a stacked quantized tree allocated up front (a donated update in
+    place), then freed: the bf16 tree is never whole on the device, and at
+    most one block's bf16 weights and the quantizer's temporaries sit on top
+    of the served tree. A block is quantized as a stack of one, through the
+    same map over the layer axis that ``quantize_model`` takes over the
+    whole stack, in one jitted program that every block reuses: the bits
+    are those of ``quantize_model`` on the whole tree under ``jit``."""
+    from repro.models.model import quantize_params
+
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    base = weights.seed_key(seed)
+    out = quantize_params(weights.rest_maker(shapes, cfg)(base), spec)
+    blk = weights.block_shapes(shapes)
+    n_layers = jax.tree_util.tree_leaves(shapes["blocks"])[0].shape[0]
+    qshapes = jax.eval_shape(lambda p: quantize_params(p, spec, path="blocks"), blk)
+    stacked = jax.jit(lambda: jax.tree_util.tree_map(
+        lambda s: jnp.zeros((n_layers,) + s.shape, s.dtype), qshapes))()
+    write = jax.jit(lambda st, q, i: jax.tree_util.tree_map(
+        lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, i, 0), st, q), donate_argnums=0)
+    make = weights.block_maker(blk, cfg)
+    quantize = jax.jit(lambda p: quantize_params(
+        jax.tree_util.tree_map(lambda x: x[None], p), spec, path="blocks"))
+    for i in range(n_layers):
+        stacked = write(stacked, quantize(make(base, i)), i)
+    out["blocks"] = jax.block_until_ready(stacked)
+    return out
+
+
 def build_engine(cfg: dict, serve: dict, seed: int):
     """(model, engine) serving ``cfg`` quantized under ``cfg["quant"]``."""
-    from repro.core import quantize_model
     from repro.models.model import build
     from repro.serving.engine import ServeConfig, ServingEngine
 
     model = build(discover.model_config(cfg))
     spec = discover.quant_spec(cfg["quant"])
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    params = weights.make_weights(shapes, cfg, seed)
-    qparams = jax.block_until_ready(quantize_model(model, params, spec))
-    del params
+    qparams = build_params(model, cfg, spec, seed)
     sc = ServeConfig.from_spec(spec, cache_len=serve["cache_len"],
                                token_budget=serve["token_budget"],
                                block_size=serve["block_size"])
@@ -318,13 +346,18 @@ def check_outputs(drv: Client, cfg: dict, mix: dict, seed: int, repo: Path,
 def reference_gaps(cfg: dict, seed: int, seqs: list, starts: list, repo: Path,
                    picks: list | None = None) -> dict:
     """Gaps of the tokens in ``seqs`` after ``starts`` (or of ``picks``,
-    the tokens another path puts first there) under the reference."""
+    the tokens another path puts first there) under the reference.
+
+    The reference runs layer by layer with every checked sequence inside:
+    its ``embed``, then ``block(cfg, x, layer_params, pos, layer)`` for each
+    layer, whose weights are made again from the seed and freed after it,
+    then ``head``. Only one block's bf16 weights are on the device at once."""
     from repro.models.model import build
 
     ref = discover.reference(cfg["reference"], repo / "perfbench")
-    model = build(discover.model_config(cfg))
-    params = weights.make_weights(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                                  cfg, seed)
+    shapes = jax.eval_shape(build(discover.model_config(cfg)).init, jax.random.PRNGKey(0))
+    base = weights.seed_key(seed)
+    rest = weights.rest_maker(shapes, cfg)(base)
     pad = 256 * -(-max(map(len, seqs)) // 256)
     toks = np.zeros((len(seqs), pad), np.int32)
     tgt = np.full((len(seqs), pad), -1, np.int32)  # token judged at each position
@@ -332,18 +365,26 @@ def reference_gaps(cfg: dict, seed: int, seqs: list, starts: list, repo: Path,
         toks[b, : len(s)] = s
         nxt = s[st + 1:] if picks is None else picks[b]
         tgt[b, st: st + len(nxt)] = nxt
+    pos = jnp.arange(pad, dtype=jnp.int32)
+
+    embed = jax.jit(lambda rest, toks: jax.lax.map(lambda t: ref.embed(rest, cfg, t), toks))
+    layer = jax.jit(lambda x, p, i: jax.lax.map(lambda xi: ref.block(cfg, xi, p, pos, i), x),
+                    donate_argnums=0)
 
     @jax.jit
-    def gaps(params, toks, tgt):
+    def gaps(rest, x, tgt):
         def one(args):
-            t, g = args
-            lg = ref.logits(params, cfg, t)
+            xi, g = args
+            lg = ref.head(rest, cfg, xi)
             chosen = jnp.take_along_axis(lg, jnp.maximum(g, 0)[:, None], axis=1)[:, 0]
             return (lg.max(-1) - chosen) / lg.std(-1)
-        return jax.lax.map(one, (toks, tgt))
+        return jax.lax.map(one, (x, tgt))
 
-    return gap_numbers(np.asarray(gaps(params, jnp.asarray(toks), jnp.asarray(tgt)))[tgt >= 0],
-                       cfg)
+    make = weights.block_maker(weights.block_shapes(shapes), cfg)
+    x = embed(rest, jnp.asarray(toks))
+    for i in range(jax.tree_util.tree_leaves(shapes["blocks"])[0].shape[0]):
+        x = layer(x, make(base, i), i)
+    return gap_numbers(np.asarray(gaps(rest, x, jnp.asarray(tgt)))[tgt >= 0], cfg)
 
 
 def device_info(dev, n_chips: int) -> dict:

@@ -7,6 +7,12 @@ halves of each head rotated as a pair) and an optional sliding window, and
 an MLP that is SiLU-gated (``wi`` holds [gate; up]) or squared-ReLU. It
 imports nothing of the serving program and reads only the parameter tree
 the benchmark made, under the serving program's key names.
+
+The harness drives it a layer at a time: ``embed``, then ``block`` once
+per layer with that layer's weights and index, then ``head``. The layer
+index picks the window: ``window_pattern``, where the configuration gives
+one, is one period of per-layer windows (0: full attention) tiled over the
+depth; otherwise every layer takes ``sliding_window``.
 """
 
 from __future__ import annotations
@@ -42,23 +48,34 @@ def _w(p):
     return p["w"].astype(jnp.float32)
 
 
-def _block(cfg: dict, x, p, pos):
+def window(cfg: dict, layer):
+    """Layer ``layer``'s attention window (0: full); ``layer`` may be traced."""
+    pattern = cfg.get("window_pattern")
+    if pattern:
+        return jnp.asarray(pattern, jnp.int32)[layer % len(pattern)]
+    return jnp.int32(cfg.get("sliding_window", 0))
+
+
+def attention(cfg: dict, x, p, pos, layer):
+    """The residual stream after the block's attention."""
     t = x.shape[0]
     kv, hd = cfg["n_kv_heads"], cfg["head_dim"]
     g = cfg["n_heads"] // kv
-    eps, kind = cfg["norm_eps"], cfg["norm"]
-    h = _norm(p["norm1"], x, kind, eps)
+    h = norm(p["norm1"], x, cfg)
     q = _rope((h @ _w(p["attn"]["wq"])).reshape(t, kv, g, hd), pos, cfg["rope_theta"])
     k = _rope((h @ _w(p["attn"]["wk"])).reshape(t, kv, hd), pos, cfg["rope_theta"])
     v = (h @ _w(p["attn"]["wv"])).reshape(t, kv, hd)
     s = jnp.einsum("skgh,tkh->kgst", q, k) * hd ** -0.5
-    ok = pos[None, :] <= pos[:, None]
-    if cfg.get("sliding_window", 0):
-        ok &= pos[None, :] > pos[:, None] - cfg["sliding_window"]
+    w = window(cfg, layer)
+    ok = (pos[None, :] <= pos[:, None]) & ((w == 0) | (pos[None, :] > pos[:, None] - w))
     s = jnp.where(ok[None, None], s, -jnp.inf)
     o = jnp.einsum("kgst,tkh->skgh", jax.nn.softmax(s, axis=-1), v)
-    x = x + o.reshape(t, -1) @ _w(p["attn"]["wo"])
-    h = _norm(p["norm2"], x, kind, eps) @ _w(p["mlp"]["wi"])
+    return x + o.reshape(t, -1) @ _w(p["attn"]["wo"])
+
+
+def mlp(cfg: dict, p, h):
+    """The MLP of ``p`` (``wi``, ``wd``) on normed activations ``h``."""
+    h = h @ _w(p["wi"])
     if cfg["act_fn"] == "silu":
         gate, up = jnp.split(h, 2, axis=-1)
         h = jax.nn.silu(gate) * up
@@ -66,15 +83,28 @@ def _block(cfg: dict, x, p, pos):
         h = jnp.square(jax.nn.relu(h))
     else:
         raise ValueError(f"reference has no activation {cfg['act_fn']!r}")
-    return x + h @ _w(p["mlp"]["wd"])
+    return h @ _w(p["wd"])
 
 
-def logits(params, cfg: dict, tokens):
-    """(T, vocab_size) float32 logits of one sequence ``tokens`` (T,)."""
+def norm(p, x, cfg: dict):
+    return _norm(p, x, cfg["norm"], cfg["norm_eps"])
+
+
+def embed(params, cfg: dict, tokens):
+    """(T, d_model) float32 inputs of one sequence ``tokens`` (T,)."""
+    return params["embed"]["table"][tokens].astype(jnp.float32)
+
+
+def block(cfg: dict, x, p, pos, layer):
+    """Block ``layer`` (weights ``p``) over one sequence's stream ``x``."""
     with jax.default_matmul_precision("highest"):
-        pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        x = params["embed"]["table"][tokens].astype(jnp.float32)
-        x, _ = jax.lax.scan(lambda c, p: (_block(cfg, c, p, pos), None), x,
-                            params["blocks"])
-        x = _norm(params["norm_f"], x, cfg["norm"], cfg["norm_eps"])
+        x = attention(cfg, x, p, pos, layer)
+        return x + mlp(cfg, p["mlp"], norm(p["norm2"], x, cfg))
+
+
+def head(params, cfg: dict, x):
+    """(T, vocab_size) float32 logits of one sequence's final stream."""
+    with jax.default_matmul_precision("highest"):
+        x = norm(params["norm_f"], x, cfg)
         return (x @ params["head"]["w"].astype(jnp.float32))[:, : cfg["vocab_size"]]
+

@@ -7,6 +7,8 @@ import shutil
 import sys
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 REPO = Path(__file__).resolve().parents[2]
@@ -20,13 +22,46 @@ for p in (str(REPO), str(REPO / "src")):
 TINY = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
         "head_dim": 16, "d_ff": 128, "vocab_size": 32000}
 
+# the program's mixture-of-experts family at smoke size (qwen2-moe's smoke
+# widths: 8 experts of 64, top-2, two shared experts), served in bf16 under
+# the chat configuration's quantization
+MOE = {"family": "moe", "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
+       "head_dim": 16, "d_ff": 64, "vocab_size": 32000, "n_experts": 8,
+       "experts_per_token": 2, "n_shared_experts": 2, "shared_expert_d_ff": 64,
+       "capacity_factor": 2.0, "sliding_window": 0, "reference": "moe_decoder"}
+
+def stacked_weights(shapes, cfg: dict, seed: int) -> dict:
+    """The whole parameter tree of ``shapes`` from ``seed``, the blocks
+    stacked: the tree that ``weights.rest_maker`` and ``weights.block_maker``
+    give piece by piece, which a run never holds whole."""
+    from perfbench import weights
+
+    base = weights.seed_key(seed)
+    make = weights.block_maker(weights.block_shapes(shapes), cfg)
+    n = jax.tree_util.tree_leaves(shapes["blocks"])[0].shape[0]
+    blocks = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *[make(base, i) for i in range(n)])
+    return dict(weights.rest_maker(shapes, cfg)(base), blocks=blocks)
+
+
+def whole_logits(ref, params, cfg: dict, tokens):
+    """(T, vocab_size) logits of one sequence under reference module ``ref``
+    over a whole stacked tree: its ``embed``, ``block`` per layer, ``head``."""
+    pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+    x, _ = jax.lax.scan(lambda c, pi: (ref.block(cfg, c, pi[0], pos, pi[1]), None),
+                        ref.embed(params, cfg, tokens),
+                        (params["blocks"], jnp.arange(n, dtype=jnp.int32)))
+    return ref.head(params, cfg, x)
+
+
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e9,
          "source": "made up for tests on the CPU"}
 
 
 def make_checkout(root: Path, cfg_name: str = "h2o_danube_1_8b",
                   traffic: str = "chat", limits=None, size=None, **mix_over) -> str:
-    """A checkout under ``root`` with one tiny cell; returns the cell's name."""
+    """A checkout under ``root`` with one tiny cell; returns the cell's name.
+    ``size`` replaces keys of configuration ``cfg_name`` (default ``TINY``)."""
     shutil.copytree(REPO / "perfbench", root / "perfbench",
                     ignore=shutil.ignore_patterns(".jax_cache", ".trace", "__pycache__"))
     cfg = json.loads((REPO / "perfbench" / "configs" / f"{cfg_name}.json").read_text())

@@ -11,6 +11,7 @@ out part of the work.
 from __future__ import annotations
 
 import json
+from collections import Counter
 from pathlib import Path
 
 import numpy as np
@@ -88,22 +89,83 @@ def paged_attention(slot_ids: np.ndarray, positions: np.ndarray, *, heads: int,
     return float(ops), float(nbytes)
 
 
+def layer_windows(cfg: dict) -> list[int]:
+    """Each layer's attention window (0: full): ``window_pattern``, one
+    period of per-layer windows tiled over the depth, where the
+    configuration gives one; else ``sliding_window`` for every layer."""
+    pattern = cfg.get("window_pattern") or [cfg.get("sliding_window", 0)]
+    return [pattern[i % len(pattern)] for i in range(cfg["n_layers"])]
+
+
+def _ff_in(cfg: dict, width: int) -> int:
+    """Output width of an MLP's input projection (gate and up for gated)."""
+    return width * (2 if cfg["act_fn"] in ("silu", "gelu") else 1)
+
+
 def projections(cfg: dict) -> list[tuple[str, int, int]]:
-    """(name, k, n) of each quantized projection of one dense decoder layer."""
+    """(name, k, n) of each projection that every token of one decoder
+    layer passes through: attention, and the dense MLP or, in a
+    mixture-of-experts layer (``n_experts``), the shared expert if any."""
     d, hd = cfg["d_model"], cfg["head_dim"]
     q, kv = cfg["n_heads"] * hd, cfg["n_kv_heads"] * hd
-    ff_in = cfg["d_ff"] * (2 if cfg["act_fn"] in ("silu", "gelu") else 1)
-    return [("attn/wq", d, q), ("attn/wk", d, kv), ("attn/wv", d, kv),
-            ("attn/wo", q, d), ("mlp/wi", d, ff_in), ("mlp/wd", cfg["d_ff"], d)]
+    out = [("attn/wq", d, q), ("attn/wk", d, kv), ("attn/wv", d, kv), ("attn/wo", q, d)]
+    if not cfg.get("n_experts"):
+        return out + [("mlp/wi", d, _ff_in(cfg, cfg["d_ff"])), ("mlp/wd", cfg["d_ff"], d)]
+    if cfg.get("n_shared_experts"):
+        f = cfg["n_shared_experts"] * cfg["shared_expert_d_ff"]
+        out += [("moe/shared/wi", d, _ff_in(cfg, f)), ("moe/shared/wd", f, d)]
+    return out
+
+
+def experts(cfg: dict) -> list[tuple[str, int, int]]:
+    """(name, k, n) of one routed expert's projections (``d_ff`` is the
+    expert width); none in a dense layer."""
+    if not cfg.get("n_experts"):
+        return []
+    d, f = cfg["d_model"], cfg["d_ff"]
+    return [("moe/wi", d, _ff_in(cfg, f)), ("moe/wd", f, d)]
+
+
+def detected_widths(cfg: dict) -> list[int]:
+    """Width of each distinct activation tensor that a layer's per-token
+    projections quantize: the attention input (shared by wq, wk, wv), wo's,
+    and the MLP's (or shared expert's) two inputs."""
+    p = projections(cfg)
+    return [p[0][1], p[3][1]] + [k for _, k, _ in p[4:]]
+
+
+def expert_gemm(rows: int, k: int, n: int, n_experts: int, k_active: int, w_bits: int,
+                a_bits: int, x_bytes: int = 2) -> tuple[float, float]:
+    """Grouped quantize + index GEMM of ``rows`` tokens, each against the
+    (k, n) weights of ``k_active`` of ``n_experts`` experts: the packed
+    indices and the codebook of the ``min(n_experts, rows * k_active)``
+    experts that some token must reach, each read once; the activation
+    codebook and boundaries, each token's activations and scale in once, a
+    float32 output per (token, expert) pair; 2*rows*k_active*k*n
+    operations. A lower bound for any implementation. No reader calls it
+    yet: it is the count for the roofline reader of a grouped expert
+    kernel, which a configuration with quantized routed experts brings with
+    its cell (ROADMAP 2.1)."""
+    used = min(n_experts, rows * k_active)
+    books = 4 * (used * 2 ** w_bits + 2 ** a_bits + 2 ** a_bits - 1)
+    nbytes = (used * k * n * storage_bits(w_bits) / 8 + books + rows * k * x_bytes + rows * 4
+              + rows * k_active * n * 4)
+    return 2.0 * rows * k_active * k * n, float(nbytes)
 
 
 def step_flops(cfg: dict, positions: np.ndarray) -> float:
     """Model operations of the real tokens of one packed step: 2 per weight
-    of every projection and of the head, plus attention over each token's
-    real, window-capped context."""
+    of every projection a token passes through (in a mixture-of-experts
+    layer its router, its ``experts_per_token`` experts and the shared
+    expert with its gate) and of the head, plus attention over each token's
+    real context, capped by each layer's window."""
+    d = cfg["d_model"]
     per_layer = sum(k * n for _, k, n in projections(cfg))
+    if cfg.get("n_experts"):
+        per_layer += d * cfg["n_experts"] + cfg["experts_per_token"] * sum(
+            k * n for _, k, n in experts(cfg))
+        per_layer += d if cfg.get("n_shared_experts") else 0
     real = positions[positions >= 0]
-    keys = attended(real, cfg.get("sliding_window", 0)).sum()
-    attn = 4.0 * keys * cfg["n_heads"] * cfg["head_dim"] * cfg["n_layers"]
-    return 2.0 * real.size * (per_layer * cfg["n_layers"]
-                              + cfg["d_model"] * cfg["vocab_size"]) + attn
+    attn = sum(4.0 * attended(real, w).sum() * cfg["n_heads"] * cfg["head_dim"] * n
+               for w, n in Counter(layer_windows(cfg)).items())
+    return 2.0 * real.size * (per_layer * cfg["n_layers"] + d * cfg["vocab_size"]) + attn
